@@ -22,6 +22,11 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+# Every dot here runs at full f32 (or f64): on a TPU the default precision
+# is one bf16 pass, which leaves ~3 significant digits — too few for
+# normal equations whose columns span δ⁰…δ² (the CPU computes f32 either way)
+_HI = jax.lax.Precision.HIGHEST
+
 # m·cols threshold above which the fused Pallas XᵀX/Xᵀy kernel is used.
 # Below it the plain jnp matmul wins (kernel launch/interpret overhead).
 GRAM_KERNEL_MIN_ELEMENTS = 32768
@@ -92,8 +97,8 @@ def fit_quadratic(deltas: jax.Array, ys: jax.Array, weights: jax.Array = None,
         rhs = rhs.astype(x.dtype)
     else:
         xw = x * weights.astype(x.dtype)[:, None] if weights is not None else x
-        gram = xw.T @ x                               # (cols, cols)
-        rhs = xw.T @ y
+        gram = jnp.matmul(xw.T, x, precision=_HI)     # (cols, cols)
+        rhs = jnp.matmul(xw.T, y, precision=_HI)
     # scale-aware ridge keeps the solve stable when columns differ in magnitude
     diag = jnp.diagonal(gram)
     lam = ridge * jnp.maximum(jnp.max(diag), 1.0)
@@ -111,8 +116,8 @@ def fit_quadratic_robust(deltas: jax.Array, ys: jax.Array,
     sample set refits to the identical surrogate."""
     w = mad_outlier_weights(ys)
     c, g, H = fit_quadratic(deltas, ys, w, ridge, use_kernel)
-    pred = c + deltas @ g + \
-        0.5 * jnp.einsum("mi,ij,mj->m", deltas, H, deltas)
+    pred = c + jnp.matmul(deltas, g, precision=_HI) + \
+        0.5 * jnp.einsum("mi,ij,mj->m", deltas, H, deltas, precision=_HI)
     w2 = w * mad_outlier_weights(ys - pred)
     return fit_quadratic(deltas, ys, w2, ridge, use_kernel)
 
@@ -134,4 +139,5 @@ def newton_direction(g: jax.Array, H: jax.Array, damping: float = 1e-6) -> jax.A
     evals, evecs = jnp.linalg.eigh(H)
     lam = jnp.maximum(damping, damping - jnp.min(evals))
     inv = 1.0 / (evals + lam)
-    return -(evecs * inv[None, :]) @ (evecs.T @ g)
+    return -jnp.matmul(evecs * inv[None, :],
+                       jnp.matmul(evecs.T, g, precision=_HI), precision=_HI)

@@ -165,11 +165,9 @@ class LmLossEvalBackend(EvalBackend):
             min_bucket = DEFAULT_MIN_BUCKET
             self.n_shards = 1
         else:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
-            from repro.configs.base import ShapeConfig
-            from repro.models.sharding import enforce_divisible, input_specs
+            from repro.models.sharding import enforce_divisible
 
             self.n_shards = int(mesh.shape[data_axis])
             if self.n_shards & (self.n_shards - 1):
@@ -183,11 +181,10 @@ class LmLossEvalBackend(EvalBackend):
             # basis leaves mirror the param leaves with a leading k axis
             bspecs = jax.tree.map(lambda s: P(*((None,) + tuple(s))), pspecs,
                                   is_leaf=lambda x: isinstance(x, P))
-            shape = ShapeConfig("lm_subspace",
-                                seq_len=batch["tokens"].shape[1],
-                                global_batch=batch["tokens"].shape[0],
-                                kind="train")
-            _, in_pspecs = input_specs(workload.cfg, shape, mesh)
+            # every lane's loss is over the WHOLE batch, so the batch is
+            # replicated: split over ``data`` (as a training step would),
+            # each shard would score its lanes on its slice of the tokens
+            in_pspecs = jax.tree.map(lambda _: P(), batch)
 
             def _gather_full(tree, specs):
                 # tiled all-gather over the model axis reconstructs each
@@ -208,10 +205,10 @@ class LmLossEvalBackend(EvalBackend):
                 basis_f = _gather_full(basis_sh, bspecs)
                 return lanes(pts, theta_f, basis_f, batch_sh)
 
-            self._sharded = shard_map(
+            self._sharded = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(P(data_axis, None), pspecs, bspecs, in_pspecs),
-                out_specs=P(data_axis), check_rep=False)
+                out_specs=P(data_axis), check_vma=False)
             # device_put with the enforced specs: θ0 and the basis are
             # STORED model-sharded (the tentpole's storage-scaling claim),
             # and shard_map consumes them without a relayout

@@ -63,7 +63,7 @@ class PodMeshEvalBackend(EvalBackend):
     def __init__(self, f_batch: Callable, mesh=None, data_axis: str = "data",
                  *, n_dims: Optional[int] = None,
                  max_bucket: Optional[int] = None):
-        from jax.experimental.shard_map import shard_map
+        import jax
         from jax.sharding import PartitionSpec as P
 
         self.mesh = make_data_mesh() if mesh is None else mesh
@@ -74,7 +74,7 @@ class PodMeshEvalBackend(EvalBackend):
                 f"data axis must be a power of two to divide the "
                 f"power-of-two buckets, got {self.n_shards}")
         self.f_batch = f_batch
-        self._sharded = shard_map(
+        self._sharded = jax.shard_map(
             f_batch, mesh=self.mesh,
             in_specs=P(data_axis, None), out_specs=P(data_axis))
         # floor of 4 rows per shard: XLA CPU picks a different (last-ulp

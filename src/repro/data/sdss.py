@@ -62,7 +62,10 @@ def _bg_density(x, q):
 def _stream_density(x, center, axis, sigma):
     """Gaussian tube around the line {center + t·axis}."""
     rel = x - center
-    along = jnp.einsum("...k,k->...", rel, axis)
+    # full f32: perp2 below cancels |rel|² against along², and the TPU's
+    # default one-pass bf16 dot would leave ~3 digits in ``along``
+    along = jnp.einsum("...k,k->...", rel, axis,
+                       precision=jax.lax.Precision.HIGHEST)
     perp2 = jnp.sum(rel * rel, axis=-1) - along ** 2
     return jnp.exp(-0.5 * perp2 / (sigma ** 2))
 

@@ -1,19 +1,14 @@
-"""Version-compat shims for Pallas API drift across jax releases.
+"""Kernel-side platform decisions, kept in one place.
 
-jax 0.4.x names the Mosaic params class ``pltpu.TPUCompilerParams``; newer
-releases renamed it to ``pltpu.CompilerParams`` (and some older ones only
-had the dict form).  Every kernel in this package routes through this
-module so the drift is absorbed in exactly one place.
+Every kernel in this package takes its Mosaic params class and its
+interpret/route defaults from here.
 """
 from __future__ import annotations
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
-# prefer the current name; fall back to the 0.4.x-era one
-CompilerParams = getattr(pltpu, "CompilerParams", None)
-if CompilerParams is None:
-    CompilerParams = pltpu.TPUCompilerParams
+CompilerParams = pltpu.CompilerParams
 
 
 def interpret_default() -> bool:

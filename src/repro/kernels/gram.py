@@ -29,9 +29,14 @@ def _gram_kernel(x_ref, y_ref, g_ref, r_ref, g_scr, r_scr):
 
     xb = x_ref[...].astype(jnp.float32)                 # (bm, c)
     yb = y_ref[...].astype(jnp.float32)                 # (bm, 1)
+    # explicit fp32 contraction: the normal equations need more than the
+    # ~3 digits of a one-pass bf16 product
+    hi = jax.lax.Precision.HIGHEST
     g_scr[...] += jax.lax.dot_general(xb, xb, (((0,), (0,)), ((), ())),
+                                      precision=hi,
                                       preferred_element_type=jnp.float32)
     r_scr[...] += jax.lax.dot_general(xb, yb, (((0,), (0,)), ((), ())),
+                                      precision=hi,
                                       preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)

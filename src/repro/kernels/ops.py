@@ -16,8 +16,6 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import gram as _gram
 from repro.kernels import wkv6 as _wkv6
 
-_interpret_default = compat.interpret_default
-
 
 def _pad_to(x, axis: int, mult: int):
     pad = (-x.shape[axis]) % mult
@@ -36,7 +34,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     GQA: q heads are grouped onto kv heads (Hq % Hkv == 0).
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = compat.interpret_default()
     b, s, hq, d = q.shape
     hkv = k.shape[2]
     g = hq // hkv
@@ -63,7 +61,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 def wkv6(r, k, v, lw, u, *, chunk: int = 256, interpret: bool | None = None):
     """r,k,v,lw: (B, T, H, K); u: (H, K) -> (B, T, H, K) — model layout."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = compat.interpret_default()
     b, t, h, kk = r.shape
     to_k = lambda a: a.transpose(0, 2, 1, 3)            # (B,H,T,K)
     c = min(chunk, t)
@@ -125,7 +123,7 @@ def gram(x, y, *, block_m: int = 512, interpret: bool | None = None):
     (zero rows contribute nothing to either product).
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = compat.interpret_default()
     m, c = x.shape
     x, pad_c = _pad_to(x, 1, 128)
     bm = min(block_m, 8 * 128)

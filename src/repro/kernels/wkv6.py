@@ -56,17 +56,27 @@ def wkv6(r, k, v, lw, u, *, chunk: int = 256, interpret: bool = False):
     assert t % chunk == 0, (t, chunk)
     grid = (b, h, t // chunk)
     kernel = functools.partial(_wkv6_kernel, chunk=chunk)
+    # The time loop reads and writes one row at a dynamic sublane offset,
+    # which Mosaic lowers for 32-bit rows only (a packed bf16 row needs an
+    # offset it can prove aligned), so the blocks travel in f32 — the
+    # kernel computes in f32 either way.
+    dtype = r.dtype
+    r, k, v, lw = (a.astype(jnp.float32) for a in (r, k, v, lw))
 
     time_spec = pl.BlockSpec((1, 1, chunk, kk), lambda bi, hi, ti: (bi, hi, ti, 0))
-    return pl.pallas_call(
+    # u enters as (H, 1, K): Mosaic tiles the last two block dims, and a
+    # (1, K) block of an (H, K) array is refused unless H == 1 — (1, K)
+    # of a (1, K) trailing slab is the full extent and always tiles
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[time_spec, time_spec, time_spec, time_spec,
-                  pl.BlockSpec((1, kk), lambda bi, hi, ti: (hi, 0))],
+                  pl.BlockSpec((1, 1, kk), lambda bi, hi, ti: (hi, 0, 0))],
         out_specs=time_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, t, kk), r.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, t, kk), jnp.float32),
         scratch_shapes=[pltpu.VMEM((kk, kk), jnp.float32)],
         compiler_params=compat.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, lw, u)
+    )(r, k, v, lw, u.reshape(h, 1, kk))
+    return out.astype(dtype)

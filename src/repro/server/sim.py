@@ -1041,6 +1041,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                          "detecting (the solo-reproducibility twin)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.problem == "lm":
         spec, fleet, wl = lm_problem(
             arch=args.arch, k=args.k, n_hosts=args.n_hosts, m=args.m,

@@ -23,7 +23,7 @@ from repro.configs import ARCH_NAMES, ShapeConfig, get_smoke_config
 from repro.models import transformer as T
 from repro.models.sharding import enforce_divisible, input_specs, param_specs
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 
 def _axis_size(entry) -> int:
