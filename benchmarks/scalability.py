@@ -136,8 +136,6 @@ def _grid_stats_row(stats):
         "ticks": stats.ticks,
         "batch_calls": stats.batch_calls,
         "mean_batch": stats.batched_evals / max(stats.batch_calls, 1),
-        "device_blocked_s": round(stats.device_blocked_s, 4),
-        "host_s": round(stats.host_s, 4),
         "spec_blocks": stats.spec_blocks,
         "spec_discarded": stats.spec_discarded,
         "max_in_flight": stats.max_in_flight,
@@ -862,9 +860,7 @@ def _lm_subspace_shootout(arch: str, k: int, m: int, iters: int,
     serve as the zero-compile probe (``compile_count`` must not move).
     Wall-clock is best-of ``LM_REPS`` alternating reps.  Unlike the sdss
     rows this workload is FLOPs-bound (each lane is a model forward), so
-    the pipelined/sync ratio is reported, not gated; the per-row
-    ``device_utilization`` (driver time blocked on device work / wall)
-    makes that regime visible in the ledger.  Returns (sync_row,
+    the pipelined/sync ratio is reported, not gated.  Returns (sync_row,
     pipelined_row, ratio, parity_ok, zero_compiles_ok)."""
     from repro.core.substrates.lm_loss import LmLossEvalBackend
     from repro.server.sim import lm_problem
@@ -896,14 +892,9 @@ def _lm_subspace_shootout(arch: str, k: int, m: int, iters: int,
     wall_sync, wall_pipe = min(t_sync), min(t_pipe)
 
     def row(substrate, engine, stats, wall, reps):
-        # utilization pairs the LAST rep's stats with the LAST rep's wall
-        # (best-of wall is a different rep; mixing them would lie)
         return {"substrate": substrate, "arch": arch, "k": k, "m": m,
                 "n_params": wl.proj.n_params, "wall_s": wall,
                 "wall_s_reps": [round(t, 4) for t in reps],
-                "device_utilization": round(
-                    min(stats.device_blocked_s / max(reps[-1], 1e-9), 1.0),
-                    4),
                 "final": engine.best_fitness,
                 "iterations": engine.iteration,
                 "completed": stats.completed, "parity_ok": parity_ok,
@@ -1030,12 +1021,9 @@ def run(out_dir=None, n_stars=8_000, smoke: bool = False,
             "n_hosts": p_hosts, "sync": sync_row, "pipelined": pipe_row,
             "speedup": pipe_speedup}
         emit(f"scal_pipelined_sync_{p_hosts}", sync_row["wall_s"] * 1e6,
-             f"m={p_m};tick={p_tick};"
-             f"dev_blk_s={sync_row['device_blocked_s']};"
-             f"ticks={sync_row['ticks']}")
+             f"m={p_m};tick={p_tick};ticks={sync_row['ticks']}")
         emit(f"scal_pipelined_{p_hosts}", pipe_row["wall_s"] * 1e6,
-             f"m={p_m};tick={p_tick};dev_blk_s={pipe_row['device_blocked_s']};"
-             f"spec={pipe_row['spec_blocks']};"
+             f"m={p_m};tick={p_tick};spec={pipe_row['spec_blocks']};"
              f"depth={pipe_row['max_in_flight']};"
              f"parity={'ok' if pipe_parity_ok else 'FAIL'}")
         emit(f"scal_pipelined_speedup_{p_hosts}", pipe_speedup,
@@ -1194,11 +1182,9 @@ def run(out_dir=None, n_stars=8_000, smoke: bool = False,
             "arch": lm_arch, "n_hosts": lm_hosts, "sync": lm_sync,
             "pipelined": lm_pipe, "pipelined_vs_sync_ratio": lm_ratio}
         emit(f"scal_lm_sync_{lm_arch}", lm_sync["wall_s"] * 1e6,
-             f"k={lm_k};m={lm_m};params={lm_sync['n_params']};"
-             f"dev_util={lm_sync['device_utilization']:.2f}")
+             f"k={lm_k};m={lm_m};params={lm_sync['n_params']}")
         emit(f"scal_lm_pipelined_{lm_arch}", lm_pipe["wall_s"] * 1e6,
              f"k={lm_k};m={lm_m};"
-             f"dev_util={lm_pipe['device_utilization']:.2f};"
              f"compiles={lm_pipe['compiles_after_warm']};"
              f"parity={'ok' if lm_parity_ok else 'FAIL'}")
         emit(f"scal_lm_pipelined_ratio_{lm_arch}", lm_ratio,

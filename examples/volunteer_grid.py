@@ -104,8 +104,7 @@ def main():
           f"simulated hours — {bstats.batch_calls} fitness batches "
           f"(mean {bstats.batched_evals / max(bstats.batch_calls, 1):.0f} "
           f"points each), {wall:.1f}s wall")
-    print(f"  ticks (DESIGN.md §7): device-blocked "
-          f"{bstats.device_blocked_s:.2f}s vs host {bstats.host_s:.2f}s, "
+    print(f"  ticks (DESIGN.md §7): {bstats.ticks}, "
           f"pipeline depth {bstats.max_in_flight}, "
           f"{bstats.spec_blocks} speculative blocks "
           f"({bstats.spec_discarded} discarded)")

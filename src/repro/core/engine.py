@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import regression, sampling
+from repro.obs.spans import span, spanned
 
 
 @functools.partial(jax.jit, static_argnames=("outlier_guard", "ridge",
@@ -265,6 +266,7 @@ class AnmEngine:
 
     # -- work generation ----------------------------------------------------
 
+    @spanned("engine.generate")
     def generate(self, k: Optional[int] = None) -> List[EvalRequest]:
         """Return up to ``k`` evaluation requests (``k=None``: the phase's
         natural batch).  While validating, only outstanding quorum replicas
@@ -296,6 +298,7 @@ class AnmEngine:
         return [EvalRequest(int(tickets[i]), phase_id, pts[i],
                             float(alphas[i])) for i in range(len(tickets))]
 
+    @spanned("engine.generate")
     def generate_block(self, k: Optional[int] = None):
         """Vectorized work generation for array-based substrates: returns
         ``(tickets (k,), phase_id, points (k, n), alphas (k,))`` with no
@@ -323,6 +326,7 @@ class AnmEngine:
 
     # -- block speculation (pipelined substrates, DESIGN.md §7) -------------
 
+    @spanned("engine.generate")
     def peek_block(self, k: Optional[int] = None):
         """Speculatively generate a block for the CURRENT phase: exactly the
         draws ``generate_block(k)`` would make, but revertible.  A pipelined
@@ -500,6 +504,7 @@ class AnmEngine:
 
     # -- assimilation -------------------------------------------------------
 
+    @spanned("engine.assimilate")
     def assimilate(self, results: Iterable[EvalResult]) -> List[Transition]:
         """Fold any completed evaluations into the phase machine.  Returns
         the phase transitions they caused (possibly none, possibly several —
@@ -572,6 +577,7 @@ class AnmEngine:
             else:
                 transitions.extend(self._finish_line_search())
 
+    @spanned("engine.assimilate")
     def assimilate_arrays(self, phase_ids: np.ndarray, tickets: np.ndarray,
                           points: np.ndarray, alphas: np.ndarray,
                           validates: np.ndarray,
@@ -648,16 +654,17 @@ class AnmEngine:
     def _finish_regression(self) -> List[Transition]:
         pts = np.concatenate(self._res_pts)
         ys = np.concatenate(self._res_ys)
-        d, a_lo, a_hi = _regression_direction(
-            jnp.asarray(pts - self.center[None, :], jnp.float32),
-            jnp.asarray(ys, jnp.float32),
-            jnp.asarray(self.center, jnp.float32),
-            jnp.asarray(self.lo, jnp.float32),
-            jnp.asarray(self.hi, jnp.float32),
-            outlier_guard=self.cfg.outlier_guard, ridge=self.cfg.ridge,
-            damping=self.cfg.damping, a_min=self.cfg.alpha_min,
-            a_max=self.cfg.alpha_max)
-        d = np.asarray(d, np.float64)
+        with span("engine.finish"):
+            d, a_lo, a_hi = _regression_direction(
+                jnp.asarray(pts - self.center[None, :], jnp.float32),
+                jnp.asarray(ys, jnp.float32),
+                jnp.asarray(self.center, jnp.float32),
+                jnp.asarray(self.lo, jnp.float32),
+                jnp.asarray(self.hi, jnp.float32),
+                outlier_guard=self.cfg.outlier_guard, ridge=self.cfg.ridge,
+                damping=self.cfg.damping, a_min=self.cfg.alpha_min,
+                a_max=self.cfg.alpha_max)
+            d = np.asarray(d, np.float64)
         if not np.all(np.isfinite(d)):
             # degenerate fit (f32 eigh/solve can overflow when corrupted
             # samples blow the surrogate up): a zero direction makes the

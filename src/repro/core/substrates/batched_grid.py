@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Callable, Dict, NamedTuple, Optional
 
 import numpy as np
@@ -49,6 +48,7 @@ from repro.core.grid import GridConfig, GridStats, sample_hosts
 from repro.core.substrates.eval_backend import (STAGING_RING, EvalBackend,
                                                 EvalHandle,
                                                 InProcessEvalBackend)
+from repro.obs.spans import spanned
 
 
 @dataclasses.dataclass
@@ -56,8 +56,6 @@ class BatchedGridStats(GridStats):
     ticks: int = 0
     batch_calls: int = 0
     batched_evals: int = 0            # delivered results summed over ticks
-    device_blocked_s: float = 0.0     # wall seconds blocked in collect()
-    host_s: float = 0.0               # wall seconds of host-side simulation
     spec_blocks: int = 0              # blocks issued speculatively (peek)
     spec_discarded: int = 0           # speculative blocks rolled back
     max_in_flight: int = 0            # deepest device pipeline reached
@@ -104,10 +102,6 @@ class _RunState:
     pending: collections.deque = dataclasses.field(
         default_factory=collections.deque)
     spec_wanted: int = 0
-    # host wall-clock accumulated inside start/step/finish calls only, so
-    # interleaved multi-search runs don't charge each other's ticks here
-    wall_s: float = 0.0
-    blocked0: float = 0.0             # device_blocked_s at start()
 
 
 class BatchedVolunteerGrid:
@@ -193,6 +187,7 @@ class BatchedVolunteerGrid:
     # is pure control inversion, which is what keeps the coalesced
     # multi-search trajectories bit-identical to solo runs.
 
+    @spanned("fleet.step")
     def start(self, engine: AnmEngine, max_ticks: int = 1_000_000,
               max_sim_time: float = float("inf")) -> None:
         """Bind an engine and begin a stepwise run.  Warms the backend's
@@ -209,11 +204,8 @@ class BatchedVolunteerGrid:
         max_live = min(n, self.warm_max_bucket(
             max(engine.cfg.m_regression, engine.cfg.m_line_search),
             self.overcommit))
-        # warm BEFORE the wall timer opens: a cold backend's one-time XLA
-        # compiles must not be booked as this run's host time
         self.backend.warm(engine.n, max_live)
-        t0 = time.perf_counter()
-        rs = _RunState(
+        self._rs = _RunState(
             engine=engine, max_ticks=max_ticks, max_sim_time=max_sim_time,
             busy=np.zeros(n, bool), lost=np.zeros(n, bool),
             t_done=np.full(n, np.inf), req_phase=np.full(n, -1),
@@ -221,10 +213,7 @@ class BatchedVolunteerGrid:
             a_validates=np.full(n, -1, np.int64),
             a_alpha=np.full(n, np.nan), a_point=np.zeros((n, engine.n)),
             # hosts come online staggered, like the per-event simulator
-            online=self.rng.uniform(0, cfg.base_eval_time / 10, n),
-            blocked0=self.stats.device_blocked_s)  # host_s per-run-sane
-        self._rs = rs
-        rs.wall_s += time.perf_counter() - t0
+            online=self.rng.uniform(0, cfg.base_eval_time / 10, n))
 
     def _issue(self, rs: _RunState, hosts, tickets, phase_id, pts, alphas,
                validates):
@@ -247,10 +236,7 @@ class BatchedVolunteerGrid:
         p = rs.pending.popleft()
         ys = np.full(p.d_phase.size, np.nan)
         if p.handle is not None:
-            t0 = time.perf_counter()
-            ys_live = self.submitter.collect(p.handle)
-            self.stats.device_blocked_s += time.perf_counter() - t0
-            ys[p.live_mask] = ys_live
+            ys[p.live_mask] = self.submitter.collect(p.handle)
             # bucket widths are recorded at collect time: a coalesced
             # lane's width is only known once the shared round dispatches
             kp = p.handle.kp
@@ -274,6 +260,7 @@ class BatchedVolunteerGrid:
         cap = int(np.ceil(wanted * self.overcommit))
         return min(idle_n, max(cap - in_flight, 0))
 
+    @spanned("fleet.step")
     def step(self) -> bool:
         """Advance the bound run by one tick.  Returns False once the run
         is over (engine done, or a tick/sim-time budget hit) — the caller
@@ -285,7 +272,6 @@ class BatchedVolunteerGrid:
         if engine.done or self.stats.ticks >= rs.max_ticks \
                 or rs.now > rs.max_sim_time:
             return False
-        t0 = time.perf_counter()
         cfg = self.cfg
         rng = self.rng
         idle = np.flatnonzero(~rs.busy & (rs.online <= rs.now))
@@ -338,7 +324,6 @@ class BatchedVolunteerGrid:
         if not rs.busy.any():
             self._flush_all(rs)
             rs.now += cfg.idle_retry
-            rs.wall_s += time.perf_counter() - t0
             return True
 
         # advance to the k-th earliest CURRENT-PHASE completion and drain
@@ -436,25 +421,18 @@ class BatchedVolunteerGrid:
                 # bootstrap/validating, whose votes decide transitions):
                 # assimilation must decide, so drain the pipeline
                 self._flush_all(rs)
-        rs.wall_s += time.perf_counter() - t0
         return True
 
+    @spanned("fleet.step")
     def finish(self) -> BatchedGridStats:
-        """Drain the pipeline, seal sim-time and the host/device wall split,
-        and release the run state.  Safe to call on a run stopped early
-        (the orchestrator's portfolio kill does exactly that)."""
+        """Drain the pipeline, seal sim-time and release the run state.
+        Safe to call on a run stopped early (the orchestrator's portfolio
+        kill does exactly that)."""
         rs = self._rs
         if rs is None:
             raise RuntimeError("no run in progress; start() one")
-        t0 = time.perf_counter()
         self._flush_all(rs)
         self.stats.sim_time = rs.now
-        rs.wall_s += time.perf_counter() - t0
-        # accumulate like every other stats field: this run's in-call wall
-        # minus this run's device-blocked share (not the all-runs
-        # cumulative, and not other searches' ticks between our steps)
-        self.stats.host_s += rs.wall_s - (self.stats.device_blocked_s
-                                          - rs.blocked0)
         self._rs = None
         return self.stats
 

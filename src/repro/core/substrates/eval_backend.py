@@ -57,6 +57,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import numpy as np
 
 from repro.core.grid import malicious_lie
+from repro.obs.spans import span, spanned
 
 #: THE bucket floor, documented once: blocks smaller than this are padded
 #: up to it so tiny phases (the bootstrap probe, quorum replicas) reuse one
@@ -204,6 +205,7 @@ class EvalBackend:
 
     # -- the async protocol --------------------------------------------------
 
+    @spanned("backend.submit")
     def submit(self, pts: np.ndarray,
                mal_u: Optional[np.ndarray] = None,
                lane_tags: Optional[np.ndarray] = None) -> EvalHandle:
@@ -236,6 +238,7 @@ class EvalBackend:
                           None if lane_tags is None
                           else np.asarray(lane_tags))
 
+    @spanned("backend.collect")
     def collect(self, handle: EvalHandle) -> np.ndarray:
         """Materialize a submitted bucket (blocks until the device is
         done), free its staging slot, and strip the pad lanes.  The slot
@@ -245,7 +248,12 @@ class EvalBackend:
         owners = self._slot_owner.get(handle.kp)
         if owners is not None and owners[handle.slot] == handle.seq:
             owners[handle.slot] = None
-        return np.asarray(handle.ys, np.float64)[:handle.k]
+        # the wait is the one blocking read-back: a separate
+        # block_until_ready() ahead of it would serialize the
+        # device-to-host copy behind it, a round trip per bucket
+        with span("backend.wait"):
+            ys = np.asarray(handle.ys, np.float64)
+        return ys[:handle.k]
 
     def __call__(self, pts: np.ndarray,
                  mal_u: Optional[np.ndarray] = None) -> np.ndarray:

@@ -40,6 +40,8 @@ import bisect
 import dataclasses
 from typing import Dict, Optional
 
+from repro.obs.spans import spanned
+
 ALIVE, SUSPECT, DEAD = "alive", "suspect", "dead"
 
 
@@ -205,6 +207,7 @@ class HostRegistry:
         rec.nowork_streak += 1
         rec.next_contact_at = now + retry_after
 
+    @spanned("intake.sweep")
     def sweep(self, now: float) -> None:
         """Lazy churn transitions from message-time silence.  Deterministic:
         driven only by the virtual timestamps messages carry."""

@@ -62,6 +62,7 @@ from repro.core.grid import GridConfig, sample_hosts
 from repro.core.orchestrator.director import SearchSpec
 from repro.core.substrates.eval_backend import EvalBackend
 from repro.core.substrates.eval_cache import CachingSubmitter, EvalCache
+from repro.obs import spans
 from repro.server import protocol
 from repro.server.chaos import ChaosTransport, FaultPlan, PRESETS
 from repro.server.checkpoint import CheckpointManager
@@ -245,11 +246,19 @@ class SimClientPool:
                 f"simulated crash after {self.stats.messages} messages")
         self.stats.messages += 1
         t0 = time.perf_counter()
-        rep = conn.call(msg)
+        if spans.enabled():
+            # one span a message, the round trip through the connection
+            # (codec both ways, WorkServer.handle): per-message wrappers
+            # deeper down would cost the untraced hot loop a frame each
+            with spans.span("intake.transport"):
+                rep = conn.call(msg)
+        else:
+            rep = conn.call(msg)
         if msg.get("kind") == "request_work":
             self.request_wall.append(time.perf_counter() - t0)
         return rep
 
+    @spans.spanned("fleet.run")
     def run(self, conn) -> PoolStats:
         cfg = self.cfg
         if not self._seeded:

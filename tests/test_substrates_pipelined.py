@@ -326,17 +326,42 @@ def test_staging_ring_overrun_raises_instead_of_corrupting():
             be.collect(h)
 
 
-def test_host_s_sane_across_repeated_runs():
-    """Stats accumulate across run() calls; host_s must stay a sane
-    per-run accumulation, not go negative from mixing a per-run wall
-    clock with the cumulative device-blocked total."""
+def test_grid_span_self_times_add_up_across_repeated_runs(monkeypatch):
+    """The grid's host time is read from program spans (``obs/spans.py``):
+    across repeated run() calls on one grid every self time stays
+    non-negative, the totals only grow, and the self times of all spans,
+    with the bookkeeping of the nested ones, add up to the grid's own
+    enclosing ``fleet.step`` time."""
+    from repro.obs import spans
+
+    monkeypatch.setattr(spans, "enabled", lambda: True)
+    spans.reset()
     f_batch, n = _quad_fitness()
     cfg = AnmConfig(m_regression=24, m_line_search=24, max_iterations=2)
     gcfg = GridConfig(n_hosts=64, failure_prob=0.05, malicious_prob=0.0,
                       seed=3)
     grid = BatchedVolunteerGrid(f_batch, gcfg)
-    for seed in (1, 2):
-        engine = AnmEngine(np.ones(n), -10 * np.ones(n), 10 * np.ones(n),
-                           0.5 * np.ones(n), cfg, seed=seed)
-        stats = grid.run(engine)
-        assert stats.host_s >= 0.0
+    before = {}
+    try:
+        for seed in (1, 2):
+            engine = AnmEngine(np.ones(n), -10 * np.ones(n), 10 * np.ones(n),
+                               0.5 * np.ones(n), cfg, seed=seed)
+            grid.run(engine)
+            tot = spans.totals()
+            assert {"fleet.step", "engine.generate", "engine.assimilate",
+                    "engine.finish", "backend.submit", "backend.collect",
+                    "backend.wait"} <= set(tot)
+            for name, t in tot.items():
+                assert 0 <= t["self_ns"] <= t["total_ns"], name
+                b = before.get(name, {"count": 0, "total_ns": 0})
+                assert t["count"] > b["count"], name
+                assert t["total_ns"] >= b["total_ns"], name
+            # the grid's own fleet.step time is the self times of all its
+            # spans plus the bookkeeping of the spans nested in it
+            assert sum(t["self_ns"] + t["overhead_ns"]
+                       for t in tot.values()) \
+                - tot["fleet.step"]["overhead_ns"] == \
+                tot["fleet.step"]["total_ns"]
+            before = tot
+    finally:
+        spans.reset()
