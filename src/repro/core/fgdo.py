@@ -35,6 +35,8 @@ Semantics reproduced from the paper:
 from __future__ import annotations
 
 import dataclasses
+import math
+from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -91,6 +93,21 @@ class FgdoAnmServer:
         self.overcommit = overcommit
         self._last_val_issue = 0.0
         self.outstanding: Dict[int, WorkUnit] = {}
+        # the feeder's live count as derived state (DESIGN.md §9), kept out
+        # of ``state_dict`` and rebuilt on first use after ``load_state``:
+        # the current-phase outstanding workunits issued within the reissue
+        # timeout, wu_id -> issued_at in issue order, so expiry pops off the
+        # front and a request costs O(1) amortised instead of a scan of the
+        # whole table.  ``_live_phase`` is the phase it was built for,
+        # ``_live_now`` the clock it has expired up to; ``_pruned_phase`` is
+        # the phase whose finished-phase prune has run.
+        self._live: "OrderedDict[int, float]" = OrderedDict()
+        self._live_phase: Optional[int] = None
+        self._live_now = float("-inf")
+        self._pruned_phase: Optional[int] = None
+        # requests the overcommit cap refused (observability only: not
+        # checkpointed, surfaced in the work server's ``status`` reply)
+        self.throttled = 0
 
     # -- engine views (back-compat surface) ---------------------------------
 
@@ -204,22 +221,22 @@ class FgdoAnmServer:
                 # grid's overcommit) instead of handing one to each of
                 # n_hosts; probes older than the reissue timeout count as
                 # lost so a dropped probe can't stall the start forever
-                live = sum(1 for wu in self.outstanding.values()
-                           if wu.phase_id == eng.phase_id and
-                           now - wu.issued_at <= self.val_reissue_timeout)
-                if live >= 2:
+                if self._live_count(now) >= 2:
                     return None
             if self.overcommit is not None:
                 # entries from finished phases only feed live counts, so
                 # they are pruned rather than held forever (their results,
                 # if they ever arrive, are assimilated from the caller's
-                # own workunit record and discarded as phase-stale)
-                for wid in [wid for wid, wu in self.outstanding.items()
-                            if wu.phase_id != eng.phase_id]:
-                    del self.outstanding[wid]
-                live = sum(1 for wu in self.outstanding.values()
-                           if now - wu.issued_at <= self.val_reissue_timeout)
-                if live >= int(np.ceil(eng.wanted() * self.overcommit)):
+                # own workunit record and discarded as phase-stale); once a
+                # phase, since only the current phase issues into the table
+                if self._pruned_phase != eng.phase_id:
+                    for wid in [wid for wid, wu in self.outstanding.items()
+                                if wu.phase_id != eng.phase_id]:
+                        del self.outstanding[wid]
+                    self._pruned_phase = eng.phase_id
+                if self._live_count(now) >= math.ceil(
+                        eng.wanted() * self.overcommit):
+                    self.throttled += 1
                     return None
             reqs = eng.generate(1)
             if not reqs:
@@ -228,14 +245,45 @@ class FgdoAnmServer:
         wu = WorkUnit(req.ticket, req.phase_id, np.asarray(req.point),
                       req.alpha, req.validates, issued_at=now)
         self.outstanding[wu.wu_id] = wu
+        if wu.phase_id == self._live_phase:
+            live = self._live
+            if live and next(reversed(live.values())) > now:
+                self._live_phase = None   # issue order broken: rebuild
+            else:
+                live[wu.wu_id] = now
         self.registry.on_issue(host_id, now)
         return wu
+
+    def _live_count(self, now: float) -> int:
+        """Current-phase outstanding workunits issued within the reissue
+        timeout at ``now``.  Entries leave the index's front once expired:
+        exact while ``now`` does not decrease, since then an expired entry
+        stays expired; a clock that runs backwards, or a new phase, makes
+        this call recount the table instead."""
+        phase_id = self.engine.phase_id
+        live = self._live
+        if self._live_phase != phase_id or now < self._live_now:
+            timeout = self.val_reissue_timeout
+            wus = sorted((wu for wu in self.outstanding.values()
+                          if wu.phase_id == phase_id
+                          and now - wu.issued_at <= timeout),
+                         key=lambda wu: wu.issued_at)
+            live = self._live = OrderedDict(
+                (wu.wu_id, wu.issued_at) for wu in wus)
+            self._live_phase = phase_id
+        else:
+            while live and (now - next(iter(live.values()))
+                            > self.val_reissue_timeout):
+                live.popitem(last=False)
+        self._live_now = now
+        return len(live)
 
     # -- assimilation -------------------------------------------------------
 
     def assimilate(self, wu: WorkUnit, y: float, host_id: int,
                    now: float) -> List[Transition]:
         self.outstanding.pop(wu.wu_id, None)
+        self._live.pop(wu.wu_id, None)
         # per-host return rate + turnaround feed reliable-host scheduling;
         # phase-staleness is knowable before the engine sees the result,
         # so the registry's per-host valid-rate costs nothing extra
@@ -283,3 +331,7 @@ class FgdoAnmServer:
                           else int(w["validates"]),
                           issued_at=float(w["issued_at"]))
             self.outstanding[wu.wu_id] = wu
+        # the live-count index is derived from the table: rebuilt, and the
+        # finished-phase prune rerun, on the next request
+        self._live_phase = None
+        self._pruned_phase = None

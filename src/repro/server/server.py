@@ -594,6 +594,8 @@ class WorkServer:
                 "phase": e.fgdo.phase,
                 "iteration": e.fgdo.engine.iteration,
                 "best": e.fgdo.engine.best_fitness,
+                # requests this process's feeder throttle refused
+                "throttled": e.fgdo.throttled,
             } for e in self.searches],
             "incumbent": best_id, "best": best_y,
             "leases": len(self.leases), "lapsed": len(self.lapsed),
