@@ -41,6 +41,7 @@ import numpy as np
 from repro.core.substrates.eval_backend import (STAGING_RING, EvalBackend,
                                                 bucket_size)
 from repro.core.substrates.eval_cache import canonical_block
+from repro.obs.spans import span, spanned
 
 
 @dataclasses.dataclass
@@ -181,7 +182,9 @@ class CoalescingSubmitter:
 
     def flush(self) -> None:
         """Dispatch the open round as ONE tagged backend bucket (no-op when
-        nothing was submitted since the last flush).
+        nothing was submitted since the last flush).  Traced, a dispatch
+        is one ``orchestrator.flush`` span and an empty flush none, so the
+        span's count is the rounds dispatched.
 
         Ring safety: submitting the (STAGING_RING)-th uncollected bucket
         of one shape would restage a buffer the device may still read, so
@@ -189,9 +192,14 @@ class CoalescingSubmitter:
         materialized early (their values are CACHED on the round — later
         lane collects slice the cache, so consumers never notice; collect
         timing is invisible to the engines by the §7 contract)."""
+        if self._open is not None:
+            with span("orchestrator.flush"):
+                self._dispatch()
+
+    def _dispatch(self) -> None:
+        """Dispatch the open round (which must exist): concatenate, dedup,
+        drain the ring, submit."""
         r = self._open
-        if r is None:
-            return
         self._open = None
         pts = r.pts[0] if len(r.pts) == 1 else np.concatenate(r.pts)
         mal_u = r.mal_u[0] if len(r.mal_u) == 1 else np.concatenate(r.mal_u)
@@ -261,10 +269,13 @@ class CoalescingSubmitter:
         ys = self.backend.collect(r.handle)
         return ys if r.src is None else ys[r.src]
 
+    @spanned("orchestrator.collect")
     def collect(self, lane: LaneSlice) -> np.ndarray:
         """Materialize one search's lanes.  The shared bucket is collected
         exactly once (first caller blocks, frees the staging slot, and
-        caches the values); later lane collects slice the cache."""
+        caches the values); later lane collects slice the cache.  Traced,
+        each call is one ``orchestrator.collect`` span and a forced
+        dispatch a nested ``orchestrator.forced`` one."""
         r = lane.round_
         if r.handle is None:
             if r is not self._open:
@@ -272,7 +283,8 @@ class CoalescingSubmitter:
                     "lane belongs to a round that was never dispatched")
             # a mid-round phase decision: dispatch what we have now
             self.stats.forced_flushes += 1
-            self.flush()
+            with span("orchestrator.forced"):
+                self._dispatch()
         if r.ys is None:
             r.ys = self._materialize(r)
         return r.ys[lane.offset:lane.offset + lane.k]

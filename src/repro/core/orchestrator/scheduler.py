@@ -30,6 +30,7 @@ from repro.core.substrates.eval_backend import (STAGING_RING, EvalBackend,
                                                 bucket_size)
 from repro.core.substrates.eval_cache import CachingSubmitter, EvalCache
 from repro.core.orchestrator.coalesce import CoalescingSubmitter
+from repro.obs.spans import spanned
 
 #: spacing of derived per-slot grid seeds (a prime, so slots never collide
 #: with each other or with small user seed offsets)
@@ -199,11 +200,15 @@ class FleetScheduler:
         return LiveSearch(spec=spec, engine=engine, grid=grid,
                           search_id=search_id)
 
+    @spanned("orchestrator.round")
     def round(self, live: Sequence[LiveSearch]) -> List[LiveSearch]:
         """One scheduling round: every live search advances one tick, then
         the shared bucket (all their submits) dispatches once.  Returns
         the searches whose runs ended this round (engine done or budget
-        hit) — the caller finalizes them."""
+        hit) — the caller finalizes them.  Traced, the round is one
+        ``orchestrator.round`` span; its self time is the scheduling loop
+        alone, as each tick is a nested ``fleet.step`` span and the
+        dispatch an ``orchestrator.flush``."""
         finished: List[LiveSearch] = []
         for ls in live:
             if ls.grid.step():

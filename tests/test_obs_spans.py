@@ -218,3 +218,137 @@ def test_core_layers_import_spans_without_the_operator_plane():
     assert {m for m in loaded if m.startswith("repro.obs")} == \
         {"repro.obs", "repro.obs.spans"}
     assert "repro.server.protocol" not in loaded
+
+
+def _quadratic_backend(n=8):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.substrates.eval_backend import InProcessEvalBackend
+
+    x_opt = jnp.asarray(np.linspace(-0.5, 0.5, n, dtype=np.float32))
+
+    @jax.jit
+    def f_batch(xs):
+        return jnp.sum((xs - x_opt[None, :]) ** 2, axis=1)
+
+    return InProcessEvalBackend(f_batch, n_dims=n, max_bucket=64)
+
+
+class _SleepyGrid:
+    """A search's grid whose tick is one ``fleet.step`` span of 5 ms."""
+
+    def step(self):
+        with spans.span("fleet.step"):
+            time.sleep(0.005)
+        return True
+
+
+def test_a_round_s_self_time_leaves_out_the_fleet_ticks(on):
+    from types import SimpleNamespace
+
+    from repro.core.grid import GridConfig
+    from repro.core.orchestrator import FleetScheduler
+
+    sched = FleetScheduler(_quadratic_backend(), GridConfig(n_hosts=64))
+    live = [SimpleNamespace(grid=_SleepyGrid()) for _ in range(3)]
+    assert sched.round(live) == []
+    tot = spans.totals()
+    rnd, tick = tot["orchestrator.round"], tot["fleet.step"]
+    assert rnd["count"] == 1 and tick["count"] == 3
+    assert tick["total_ns"] >= 15_000_000
+    assert rnd["self_ns"] == \
+        rnd["total_ns"] - tick["total_ns"] - tick["overhead_ns"]
+    assert rnd["self_ns"] < tick["total_ns"]
+    # nothing was submitted: the round's flush dispatched nothing
+    assert "orchestrator.flush" not in tot
+
+
+def test_a_flush_is_counted_once_a_dispatched_round(on):
+    import numpy as np
+
+    from repro.core.orchestrator import CoalescingSubmitter
+
+    co = CoalescingSubmitter(_quadratic_backend())
+    co.flush()                                   # empty: no span
+    assert spans.totals() == {}
+    rng = np.random.default_rng(0)
+    lanes = [co.submit(tag, rng.normal(size=(k, 8)))
+             for tag, k in ((0, 3), (1, 5))]
+    co.flush()
+    co.flush()                                   # empty again
+    tot = spans.totals()
+    assert tot["orchestrator.flush"]["count"] == 1
+    assert "orchestrator.forced" not in tot
+    assert co.stats.dispatches == 1
+    assert [len(co.collect(lane)) for lane in lanes] == [3, 5]
+    tot = spans.totals()
+    assert tot["orchestrator.collect"]["count"] == 2
+    assert tot["orchestrator.flush"]["count"] == 1
+    # the backend's spans nest in the coalescer's, and stay theirs
+    assert tot["backend.submit"]["count"] == 1
+    assert tot["backend.collect"]["count"] == 1
+
+
+def test_a_collect_on_the_open_round_counts_one_forced_dispatch(on):
+    import numpy as np
+
+    from repro.core.orchestrator import CoalescingSubmitter
+
+    co = CoalescingSubmitter(_quadratic_backend())
+    rng = np.random.default_rng(1)
+    first = co.submit(0, rng.normal(size=(4, 8)))
+    second = co.submit(1, rng.normal(size=(2, 8)))
+    assert len(co.collect(first)) == 4           # forces the open round
+    assert len(co.collect(second)) == 2          # already dispatched
+    co.flush()                                   # nothing left open
+    tot = spans.totals()
+    assert tot["orchestrator.forced"]["count"] == 1
+    assert tot["orchestrator.collect"]["count"] == 2
+    assert "orchestrator.flush" not in tot
+    assert co.stats.forced_flushes == 1 and co.stats.dispatches == 1
+    forced = tot["orchestrator.forced"]
+    assert 0 <= forced["self_ns"] <= forced["total_ns"]
+
+
+def test_a_traced_portfolio_counts_its_dispatches_and_commits_as_untraced(
+        on, monkeypatch):
+    """Over a small coalesced portfolio the spans count the coalescer's
+    dispatches, whole and forced, and the searches commit what they
+    commit with the spans off."""
+    import numpy as np
+
+    from repro.core.anm import AnmConfig
+    from repro.core.engine import identical_trajectories
+    from repro.core.grid import GridConfig
+    from repro.core.orchestrator import (FleetScheduler, SearchDirector,
+                                         multi_start_specs)
+
+    def portfolio():
+        sched = FleetScheduler(_quadratic_backend(),
+                               GridConfig(n_hosts=128, failure_prob=0.1,
+                                          malicious_prob=0.02, seed=3))
+        anm = AnmConfig(m_regression=24, m_line_search=24, max_iterations=2)
+        specs = multi_start_specs(sched, np.ones(8), -5 * np.ones(8),
+                                  5 * np.ones(8), 0.5 * np.ones(8), anm, 4,
+                                  seed=0, jitter=0.3)
+        return SearchDirector(sched, specs).run(), sched
+
+    traced, sched = portfolio()
+    tot = spans.totals()
+    st = sched.coalescer.stats
+    assert st.forced_flushes > 0
+    assert tot["orchestrator.forced"]["count"] == st.forced_flushes
+    assert tot["orchestrator.flush"]["count"] == \
+        st.dispatches - st.forced_flushes
+    assert tot["orchestrator.round"]["count"] == traced.rounds
+    for name in ("orchestrator.round", "orchestrator.flush",
+                 "orchestrator.forced", "orchestrator.collect"):
+        assert 0 <= tot[name]["self_ns"] <= tot[name]["total_ns"], name
+    spans.reset()
+    monkeypatch.setattr(spans, "enabled", lambda: False)
+    plain, _ = portfolio()
+    assert spans.totals() == {}
+    for a, b in zip(traced.outcomes, plain.outcomes):
+        assert identical_trajectories(a.engine, b.engine)
+        assert a.engine.stats == b.engine.stats
