@@ -5,14 +5,22 @@ HOW that block of points turns into fitness values.  Since the pipelined
 refactor the seam is an asynchronous two-call protocol:
 
     handle = backend.submit(pts, mal_u)    # frame + dispatch, returns now
-    ys = backend.collect(handle)           # block on the device, unpad
+    ys = backend.collect(handle)           # wait for the values, unpad
 
 ``submit`` leans on JAX async dispatch: it returns as soon as the bucket
 is enqueued on the device, so the caller overlaps host simulation work
 (fleet physics, speculative work generation) with the evaluation and only
-pays for the device when ``collect`` materializes the result.  The
-synchronous form ``backend(pts, mal_u)`` remains ``collect(submit(...))``,
-so non-pipelined callers are unchanged.
+pays for the device when ``collect`` materializes the result.  ``submit``
+also requests the bucket's device-to-host copy right after the dispatch
+(``copy_to_host_async``): the device queues it behind the evaluation, so
+a bucket collected ticks later finds its values already in host memory,
+and ``collect`` only waits for a copy already under way.  Every collected
+bucket is read back anyway, so no transfer is added; only its start
+moves.  There is no ``block_until_ready`` before the read-back: it adds
+a round trip a bucket and lost on the chip (PERF.md).  When a bucket is
+collected stays invisible to the engines: the values are the same bits.
+The synchronous form ``backend(pts, mal_u)`` remains
+``collect(submit(...))``, so non-pipelined callers are unchanged.
 
 Framing.  Every block of ``k`` points is written into a PERSISTENT
 per-bucket staging buffer padded up to a power-of-two bucket (pad lanes
@@ -86,8 +94,9 @@ class EvalHandle(NamedTuple):
     """An in-flight bucket evaluation returned by ``EvalBackend.submit``.
 
     ``ys`` is the (kp,) device array still materializing under async
-    dispatch; touching it with ``np.asarray`` (what ``collect`` does)
-    blocks until the device is done.  ``k`` is the number of real lanes,
+    dispatch, its host copy already requested; touching it with
+    ``np.asarray`` (what ``collect`` does) blocks until that copy is in
+    host memory.  ``k`` is the number of real lanes,
     ``kp`` the padded bucket width, ``slot`` the staging-ring slot the
     bucket aliases until collected, and ``seq`` the submission's ownership
     token for that slot (a stale or double ``collect`` must not free a
@@ -233,24 +242,27 @@ class EvalBackend:
             buf[k:] = buf[k - 1]
             ubuf[k:] = np.nan
         self._warmed.add((n, kp))    # a lazy compile still warms the cell
-        return EvalHandle(self._eval(buf, ubuf, np.int32(k)), k, kp, slot,
-                          self._submit_seq,
+        ys = self._eval(buf, ubuf, np.int32(k))
+        # the read-back is requested now, queued behind the bucket on the
+        # device, so a deferred collect finds the values already on host
+        ys.copy_to_host_async()
+        return EvalHandle(ys, k, kp, slot, self._submit_seq,
                           None if lane_tags is None
                           else np.asarray(lane_tags))
 
     @spanned("backend.collect")
     def collect(self, handle: EvalHandle) -> np.ndarray:
-        """Materialize a submitted bucket (blocks until the device is
-        done), free its staging slot, and strip the pad lanes.  The slot
-        is freed only if this handle still OWNS it — a double collect, or
-        one stale across a ring reallocation, must not clear the flag
-        guarding a newer in-flight submission."""
+        """Materialize a submitted bucket (blocks until the host copy that
+        ``submit`` started has landed), free its staging slot, and strip
+        the pad lanes.  The slot is freed only if this handle still OWNS
+        it — a double collect, or one stale across a ring reallocation,
+        must not clear the flag guarding a newer in-flight submission."""
         owners = self._slot_owner.get(handle.kp)
         if owners is not None and owners[handle.slot] == handle.seq:
             owners[handle.slot] = None
-        # the wait is the one blocking read-back: a separate
-        # block_until_ready() ahead of it would serialize the
-        # device-to-host copy behind it, a round trip per bucket
+        # the wait is the one blocking read-back, of a copy submit has
+        # already started: a separate block_until_ready() ahead of it
+        # would only add a round trip per bucket
         with span("backend.wait"):
             ys = np.asarray(handle.ys, np.float64)
         return ys[:handle.k]

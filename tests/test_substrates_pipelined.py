@@ -13,7 +13,9 @@ The contracts under test:
     block and ``cancel_block`` leaves no trace on the rng stream, tickets
     or stats;
   * malicious corruption and pad masking are applied on-device from the
-    mask lanes shipped with the bucket.
+    mask lanes shipped with the bucket;
+  * ``submit`` starts every bucket's host copy at dispatch, and a bucket
+    collected at once, late or out of order returns the same bits.
 """
 import jax
 import jax.numpy as jnp
@@ -286,6 +288,67 @@ def test_async_submit_collect_matches_sync_call():
     handles = [be.submit(p) for p in blocks]       # all in flight at once
     for p, h in zip(blocks, handles):
         np.testing.assert_array_equal(be.collect(h), be(p))
+
+
+@pytest.mark.parametrize("backend_cls", [InProcessEvalBackend,
+                                         PodMeshEvalBackend])
+def test_submit_starts_the_host_copy_of_every_bucket(backend_cls,
+                                                     monkeypatch):
+    """The read-back is requested at dispatch, once a bucket and for the
+    bucket's own result; warming the ladder requests none."""
+    from jax._src.array import ArrayImpl
+    copied = []
+    start = ArrayImpl.copy_to_host_async
+
+    def counting(self):
+        copied.append(self)
+        return start(self)
+
+    monkeypatch.setattr(ArrayImpl, "copy_to_host_async", counting)
+    f_batch, n = _quad_fitness()
+    be = backend_cls(f_batch, n_dims=n, max_bucket=64)
+    assert copied == []
+    rng = np.random.default_rng(4)
+    handles = [be.submit(rng.uniform(-1, 1, (k, n))) for k in (1, 9, 64)]
+    assert len(copied) == 3
+    assert all(c is h.ys for c, h in zip(copied, handles))
+    for h in handles:
+        be.collect(h)
+    be(rng.uniform(-1, 1, (5, n)))
+    assert len(copied) == 4
+
+
+#: a small ladder: bucket floor, remainders, a full bucket, two doublings
+LADDER = (1, 5, 8, 13, 32, 50)
+
+
+@pytest.mark.parametrize("k", LADDER)
+def test_collect_order_and_lateness_keep_values_bit_identical(k):
+    """A handle collected at once, one collected after later buckets, and
+    handles collected in reverse order all return what the synchronous
+    call returns, bit for bit: honest lanes, malicious lanes, and the pad
+    lanes the device masks to NaN."""
+    f_batch, n = _quad_fitness()
+    be = InProcessEvalBackend(f_batch)
+    rng = np.random.default_rng(10 + k)
+    blocks = []
+    for _ in range(3):
+        u = np.where(rng.random(k) < 0.4, rng.uniform(0.2, 0.8, k), np.nan)
+        blocks.append((rng.uniform(-1, 1, (k, n)), u))
+    sync = [be(p, u) for p, u in blocks]
+    assert any((~np.isnan(u)).any() for _, u in blocks)
+
+    assert_equal = np.testing.assert_array_equal
+    assert_equal(be.collect(be.submit(*blocks[0])), sync[0])   # at once
+    late = be.submit(*blocks[0])
+    for (p, u), ref in zip(blocks[1:], sync[1:]):               # in between
+        assert_equal(be.collect(be.submit(p, u)), ref)
+    assert_equal(be.collect(late), sync[0])                     # late
+    handles = [be.submit(p, u) for p, u in blocks]
+    for h, ref in reversed(list(zip(handles, sync))):          # reversed
+        assert_equal(be.collect(h), ref)
+        pad = np.asarray(h.ys)[h.k:]
+        assert pad.size == h.kp - k and np.isnan(pad).all()
 
 
 def test_staging_ring_survives_deep_inflight_reuse():
