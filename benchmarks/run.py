@@ -1,20 +1,12 @@
-"""Benchmark harness entry point: one module per paper table/figure.
+"""Paper-figure entry point: one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV.  ``python -m benchmarks.run``.
-
-``--smoke`` runs a CI-sized subset (currently the scalability module's
-substrate + pipelined + multi-search shootouts, including the pod-mesh
-parity, sharding-overhead, pipelined-vs-sync and coalesced-vs-serial
-parity/speedup gates) so regressions in the batched grid substrate, its
-evaluation backends, the pipelined tick loop and the multi-search
-orchestrator are caught on every push without paying for the full
-sweeps.  The shootouts also refresh the repo-root
-``BENCH_scalability.json`` perf ledger (platform-stamped per entry).
+The figures compare iteration counts and fitness with the paper; speed is
+measured on the chip by ``bench/`` alone.
 """
 from __future__ import annotations
 
 import argparse
-import inspect
 import sys
 import traceback
 
@@ -22,34 +14,21 @@ MODULES = [
     ("fig2_convergence", "paper Fig. 2 — ANM convergence on two stripes"),
     ("fig3_linesearch", "paper Fig. 3 — randomized line search escapes"),
     ("anm_vs_baselines", "paper §VI — ANM vs CGD vs numerical Newton"),
-    ("scalability", "paper §I/§VI — hosts & fault sweeps + substrate shootout"),
-    ("kernel_perf", "Pallas kernels (interpret) vs oracles"),
-    ("train_throughput", "training substrate + paper-technique overhead"),
-    ("roofline", "deliverable (g) — roofline table from dry-run artifacts"),
 ]
-
-SMOKE_MODULES = ["scalability"]
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, help="run a single benchmark")
-    ap.add_argument("--smoke", action="store_true",
-                    help="fast CI subset (batched-grid perf canary)")
     args = ap.parse_args()
     failures = 0
     for name, desc in MODULES:
         if args.only and args.only != name:
             continue
-        if args.smoke and name not in SMOKE_MODULES:
-            continue
         print(f"# === {name}: {desc} ===", flush=True)
         try:
             mod = __import__(f"benchmarks.{name}", fromlist=["run"])
-            if args.smoke and "smoke" in inspect.signature(mod.run).parameters:
-                mod.run(smoke=True)
-            else:
-                mod.run()
+            mod.run()
         except Exception:
             failures += 1
             traceback.print_exc()

@@ -342,15 +342,14 @@ def phase_served(backend, spec) -> dict:
     log(f"[served] warm {t_warm:.2f}s; {engine.iteration} iterations in "
         f"{wall:.3f}s wall; {c.messages} messages handled, "
         f"{c.leases_issued} leases granted, {c.nowork_replies} no-work "
-        f"replies, request_work p99 {res.request_p99_ms:.4f} ms; "
-        f"{res.pool.evals} evaluations in {res.pool.eval_batches} "
+        f"replies; {res.pool.evals} evaluations in {res.pool.eval_batches} "
         f"dispatches, compiles during run {grown}; bootstrap {f0:.6f} -> "
         f"{[round(r.best_fitness, 6) for r in engine.history]}")
     check(grown == 0, f"served: {grown} compiles after warm()")
     check(engine.iteration >= 1, "served: no iteration committed")
     _committed(engine, f0, "served")
     return {"engine": engine, "wall_s": wall, "messages": c.messages,
-            "leases": c.leases_issued, "p99_ms": res.request_p99_ms}
+            "leases": c.leases_issued}
 
 
 # -- phase 6: the LM objective -------------------------------------------------

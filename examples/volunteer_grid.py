@@ -17,7 +17,7 @@ without edits:
     PYTHONPATH=src python examples/volunteer_grid.py
     PYTHONPATH=src python examples/volunteer_grid.py --no-pipelined
     PYTHONPATH=src python examples/volunteer_grid.py \
-        --substrate pod_mesh --pipeline-depth 6
+        --backend pod_mesh --pipeline-depth 6
 """
 import argparse
 import time
@@ -45,7 +45,7 @@ def main():
                          "bucket synchronously")
     ap.add_argument("--pipeline-depth", type=int, default=4,
                     help="max in-flight tick buckets when pipelined")
-    ap.add_argument("--substrate", default="in_process",
+    ap.add_argument("--backend", default="in_process",
                     choices=["in_process", "pod_mesh"],
                     help="evaluation backend for act 2 (act 3 runs the "
                          "OTHER backend for the parity comparison)")
@@ -84,7 +84,7 @@ def main():
     f_batch, _ = sdss.make_fitness(stripe)
     backends = {"in_process": lambda: InProcessEvalBackend(f_batch),
                 "pod_mesh": lambda: PodMeshEvalBackend(f_batch)}
-    backend2 = backends[args.substrate]()
+    backend2 = backends[args.backend]()
     engine = AnmEngine(x0, sdss.LO, sdss.HI, sdss.DEFAULT_STEP,
                        AnmConfig(m_regression=128, m_line_search=128,
                                  max_iterations=8),
@@ -97,7 +97,7 @@ def main():
         backend=backend2, pipelined=args.pipelined,
         pipeline_depth=args.pipeline_depth).run(engine)
     wall = time.perf_counter() - t0
-    print(f"batched grid (4096 hosts, {args.substrate} backend, "
+    print(f"batched grid (4096 hosts, {args.backend} backend, "
           f"{'pipelined' if args.pipelined else 'sync'}): "
           f"{engine.best_fitness:.5f} in "
           f"{engine.iteration} iterations / {bstats.sim_time / 3600:.1f} "
@@ -111,10 +111,10 @@ def main():
 
     # -- act 3: the same grid through the OTHER backend ----------------------
     # (DESIGN.md §6 — on this CPU the pod mesh degenerates to the available
-    # devices; run under repro.launch.dryrun --substrate pod_mesh for the
-    # real 16x16 partitioning.  Same seed => bit-identical iterates, on
-    # either backend, pipelined or not.)
-    other = "pod_mesh" if args.substrate == "in_process" else "in_process"
+    # devices; tests/test_sigkill_restore.py runs the real 16x16
+    # partitioning on 512 forced host devices.  Same seed => bit-identical
+    # iterates, on either backend, pipelined or not.)
+    other = "pod_mesh" if args.backend == "in_process" else "in_process"
     backend3 = backends[other]()
     engine2 = AnmEngine(x0, sdss.LO, sdss.HI, sdss.DEFAULT_STEP,
                         AnmConfig(m_regression=128, m_line_search=128,
@@ -129,7 +129,7 @@ def main():
     identical = identical_trajectories(engine, engine2)
     print(f"{other} backend: {engine2.best_fitness:.5f} — iterates "
           f"{'bit-identical to' if identical else 'DIVERGED from'} "
-          f"the {args.substrate} backend")
+          f"the {args.backend} backend")
 
 
 if __name__ == "__main__":

@@ -4,8 +4,8 @@ Hosts are heterogeneous (lognormal speeds), unreliable (may never return a
 result) and possibly malicious (return corrupted fitness).  The simulator
 drives any server exposing generate_work/assimilate — i.e. FgdoAnmServer.
 
-Deterministic given a seed; used by the fault-tolerance tests and the
-scalability benchmark (time-to-solution vs. #hosts, paper §VI discussion).
+Deterministic given a seed; used by the fault-tolerance tests and as the
+per-event reference the batched grid is held to.
 
 This simulator evaluates ONE point per Python event, which makes it the
 fidelity reference, not the fast path: at thousands of hosts the run is

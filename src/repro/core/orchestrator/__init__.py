@@ -16,8 +16,8 @@ Three layers, innermost first:
 
 The hard contract: orchestration changes WHEN lanes are evaluated, never
 what any engine sees — every orchestrated search commits bit-identical
-iterates to the same spec run alone (tests/test_orchestrator.py, the
-``--substrate multi_search`` dryrun smoke, and the benchmark gates).
+iterates to the same spec run alone (tests/test_orchestrator.py, and
+``solo_parity`` in ``bench/drivers/fleet.py`` on the chip).
 """
 from repro.core.orchestrator.coalesce import (  # noqa: F401
     CoalesceStats, CoalescingSubmitter, LaneSlice)

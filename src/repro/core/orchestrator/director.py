@@ -82,7 +82,7 @@ class SearchSpec:
         """THE parity baseline: this spec's engine alone on this spec's
         sub-fleet over ``backend``.  The knobs mirror `FleetScheduler`'s
         — pass the scheduler's values when checking an orchestrated run.
-        Every parity gate (tests, dryrun smoke, benchmark) calls this one
+        Every parity check (tests, benchmark) calls this one
         helper, so a spec field can't be silently dropped from the
         contract."""
         engine = self.build_engine()

@@ -20,7 +20,7 @@ grid predicts phase flips exactly (a phase flips iff the queued live
 results reach the phase's remaining ``wanted()``), drains the pipeline
 with ``collect`` only when assimilation must decide a transition, and the
 committed iterates are bit-identical to the non-pipelined path at the same
-seed — the hard parity contract, gated in tests, dryrun and the shootout.
+seed — the hard parity contract (tests/test_substrates_pipelined.py).
 
 It drives the ``AnmEngine`` event API directly: requests out, results in,
 in completion-time order, so stale filtering and quorum validation behave
@@ -173,7 +173,7 @@ class BatchedVolunteerGrid:
         tick (the issuance cap plus object-path slack) — THE formula for
         pre-warming a backend's bucket ladder.  ``run()`` warms with this
         internally; external callers that construct warmed backends
-        (benchmarks, dryrun) must use it too, or a changed ``overcommit``
+        (benchmarks, tests) must use it too, or a changed ``overcommit``
         would silently re-introduce mid-run compiles inside their timed
         windows."""
         return int(np.ceil(m * overcommit)) + 8

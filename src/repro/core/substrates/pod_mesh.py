@@ -4,7 +4,7 @@ This is the ROADMAP's "wire the batched grid to the pod mesh" step: instead
 of evaluating each tick's workunit block with one local ``f_batch`` call,
 ``PodMeshEvalBackend`` partitions the padded bucket over the ``data`` axis
 of the production mesh (``launch/mesh.py::make_production_mesh``, 16×16 =
-256 devices under dryrun's forced 512-device host platform) and lets every
+256 devices when 512 host devices are forced) and lets every
 data shard evaluate its ``kp / n_shards`` rows in parallel.  The ``model``
 axis is left for the fitness function itself (a replicated closure today;
 a model-sharded likelihood slots in without touching the grid).
@@ -26,7 +26,8 @@ Key properties (DESIGN.md §6):
   * rows are evaluated by the SAME per-row computation as in-process
     (``f_batch`` is row-independent), so a given engine seed commits
     bit-identical iterates on either backend — pinned by
-    tests/test_substrates_pod_mesh.py and the shootout's parity gate.
+    tests/test_substrates_pod_mesh.py, and on the 16×16 mesh by
+    tests/test_sigkill_restore.py.
 """
 from __future__ import annotations
 
@@ -37,8 +38,10 @@ from repro.core.substrates.eval_backend import EvalBackend, bucket_size
 
 def make_data_mesh():
     """Best evaluation mesh for the visible devices: the production pod
-    when enough devices exist (e.g. under ``launch/dryrun``'s forced host
-    platform), else the largest power-of-two data-parallel mesh that fits
+    when enough devices exist (e.g. under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=512``, as
+    ``tests/test_sigkill_restore.py`` runs it), else the largest
+    power-of-two data-parallel mesh that fits
     — down to a degenerate (1, 1) mesh on a single-device CPU, which keeps
     the shard_map path importable and testable anywhere."""
     import jax
@@ -81,8 +84,8 @@ class PodMeshEvalBackend(EvalBackend):
         # divergent) vectorization for 2-row sub-batches (observed on jax
         # 0.4.37 — every other width is bitwise-stable), and bit-identical
         # iterates vs the in-process backend are a hard contract of this
-        # seam.  The parity gates (tests + dryrun smoke + shootout) exist
-        # to catch any future regression of this property.
+        # seam.  The parity tests exist to catch any future regression
+        # of this property.
         super().__init__(bucket_size(4 * self.n_shards))
         if n_dims is not None and max_bucket is not None:
             self.warm(n_dims, max_bucket)

@@ -5,45 +5,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 For each cell we build ShapeDtypeStruct stand-ins for params / optimizer
 state / inputs / caches (NO device allocation), jit the step with explicit
-in/out shardings, ``.lower().compile()`` against the production mesh, and
-record memory_analysis / cost_analysis / per-kind collective bytes into
-artifacts/dryrun/<arch>__<shape>__<mesh>.json for the roofline report.
-
-``--substrate pod_mesh`` instead runs the batched-grid substrate smoke:
-the same ANM workload through the in-process backend (synchronous and
-PIPELINED tick loops) and the pipelined shard_map pod-mesh backend on the
-forced 512-device host platform, requiring bit-identical committed
-iterates across all three (DESIGN.md §6–§7) — so the production
-partitioning AND the async submit/collect path are exercised on CPU
-before any TPU time is spent.
-
-``--substrate multi_search`` runs the multi-search orchestrator smoke
-(DESIGN.md §8): a heterogeneous portfolio of concurrent ANM searches
-coalesced over ONE shared backend — in-process and shard_map'd over the
-production mesh — where every orchestrated search must commit
-bit-identical iterates to the same spec run alone on the same backend.
-
-``--substrate server`` runs the service-layer kill/restore smoke
-(DESIGN.md §9): a seeded search through the work server + simulated
-client fleet, SIGKILLed mid-search and restored from its snapshot +
-replay log — the restored run must commit bit-identical final iterates
-and identical final engine stats vs the same spec run uninterrupted, on
-BOTH the loopback and the TCP transport, and the in-process and pod-mesh
-evaluation paths must agree.
-
-The substrate names, descriptions and runners live in ONE registry
-(``repro/launch/substrates.py``) — argparse ``choices`` derive from it
-(an unknown name fails at parse time) and ``--list-substrates`` prints
-it; ``benchmarks/scalability.py`` validates its own substrate filter
-against the same dict.
+in/out shardings, ``.lower().compile()`` against the production mesh of
+512 forced host devices, and record memory_analysis / cost_analysis /
+per-kind collective bytes and the roofline terms of
+``repro.roofline.analysis`` into
+artifacts/dryrun/<arch>__<shape>__<mesh>.json.
 
 Usage:
     python -m repro.launch.dryrun --arch qwen2-72b --shape train_4k
     python -m repro.launch.dryrun --all [--mesh pod|multipod|both] [--skip-existing]
-    python -m repro.launch.dryrun --substrate pod_mesh
-    python -m repro.launch.dryrun --substrate multi_search
-    python -m repro.launch.dryrun --substrate server
-    python -m repro.launch.dryrun --list-substrates
 """
 import argparse
 import functools
@@ -52,12 +22,10 @@ import time
 import traceback
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_NAMES, SHAPES, cell_is_runnable, get_config
 from repro.launch.mesh import make_production_mesh
-from repro.launch.substrates import SUBSTRATES, list_substrates
 from repro.models import (
     ShardCtx, cache_specs, init_cache, init_params, input_specs,
     make_prefill_step, make_serve_step, make_train_step, mesh_axes, param_specs,
@@ -213,1319 +181,6 @@ def run_cell(arch, shape_name, multi_pod, out_dir, skip_existing=False,
         return False
 
 
-def run_substrate_smoke(out_dir: str, m: int = 32, iterations: int = 2,
-                        n_stars: int = 500, n_hosts: int = 512) -> bool:
-    """Pod-mesh + pipelined substrate smoke (``--substrate pod_mesh``).
-
-    Runs the SAME batched-grid workload three ways — in-process backend
-    with the synchronous tick loop (the reference), in-process PIPELINED,
-    and ``PodMeshEvalBackend`` pipelined with every bucket shard_mapped
-    over the production (data=16, model=16) mesh of forced host devices —
-    and requires identical committed centers, fitness history and
-    iteration counts across all three (DESIGN.md §6–§7).  Writes
-    artifacts/dryrun/substrate_pod_mesh.json; returns pass/fail.
-    """
-    import numpy as np
-    from repro.core.anm import AnmConfig
-    from repro.core.engine import AnmEngine, identical_trajectories
-    from repro.core.grid import GridConfig
-    from repro.core.substrates.batched_grid import BatchedVolunteerGrid
-    from repro.core.substrates.pod_mesh import PodMeshEvalBackend
-    from repro.data import sdss
-
-    mesh = make_production_mesh()
-    stripe = sdss.make_stripe("podmesh_smoke", n_stars=n_stars, seed=17)
-    f_batch, _ = sdss.make_fitness(stripe)
-    rng = np.random.default_rng(3)
-    x0 = np.clip(stripe.truth + rng.normal(0, 0.2, 8).astype(np.float32),
-                 sdss.LO, sdss.HI)
-    anm_cfg = AnmConfig(m_regression=m, m_line_search=m,
-                        max_iterations=iterations)
-    grid_cfg = GridConfig(n_hosts=n_hosts, failure_prob=0.05,
-                          malicious_prob=0.01, seed=9)
-
-    def run_with(backend, pipelined):
-        engine = AnmEngine(x0, sdss.LO, sdss.HI, sdss.DEFAULT_STEP,
-                           anm_cfg, seed=7)
-        t0 = time.time()
-        stats = BatchedVolunteerGrid(f_batch, grid_cfg, backend=backend,
-                                     pipelined=pipelined).run(engine)
-        return engine, stats, time.time() - t0
-
-    # one backend per evaluation target, shared across loop modes and
-    # warmed over the whole bucket ladder at construction, so NO timed
-    # window below pays a compile — otherwise the first (sync) run would
-    # absorb the ladder and bias the sync-vs-pipelined comparison
-    from repro.core.substrates.eval_backend import (InProcessEvalBackend,
-                                                    bucket_size)
-    max_bucket = bucket_size(BatchedVolunteerGrid.warm_max_bucket(m))
-    in_backend = InProcessEvalBackend(f_batch, n_dims=8,
-                                      max_bucket=max_bucket)
-    e_in, s_in, t_in = run_with(in_backend, False)   # in-process, sync
-    e_pin, s_pin, t_pin = run_with(in_backend, True)  # in-process, pipelined
-    pod = PodMeshEvalBackend(f_batch, mesh=mesh, n_dims=8,
-                             max_bucket=max_bucket)
-    e_pod, s_pod, t_pod = run_with(pod, True)         # pod mesh, pipelined
-
-    centers_equal = (
-        len(e_in.history) == len(e_pod.history) and
-        all(np.array_equal(a.center, b.center)
-            for a, b in zip(e_in.history, e_pod.history)))
-    fitness_equal = [r.best_fitness for r in e_in.history] == \
-        [r.best_fitness for r in e_pod.history]
-    pipelined_ok = identical_trajectories(e_in, e_pin)
-    pod_ok = identical_trajectories(e_in, e_pod)
-    ok = pipelined_ok and pod_ok
-    report = {
-        "mesh": "16x16", "data_shards": pod.n_shards,
-        "min_bucket": pod.min_bucket, "n_hosts": n_hosts, "m": m,
-        "iterations": {"in_process": e_in.iteration,
-                       "in_process_pipelined": e_pin.iteration,
-                       "pod_mesh": e_pod.iteration},
-        "final": {"in_process": e_in.best_fitness,
-                  "in_process_pipelined": e_pin.best_fitness,
-                  "pod_mesh": e_pod.best_fitness},
-        "batch_calls": {"in_process": s_in.batch_calls,
-                        "in_process_pipelined": s_pin.batch_calls,
-                        "pod_mesh": s_pod.batch_calls},
-        "wall_s": {"in_process": round(t_in, 3),
-                   "in_process_pipelined": round(t_pin, 3),
-                   "pod_mesh": round(t_pod, 3)},
-        "pipeline": {"spec_blocks": s_pin.spec_blocks,
-                     "spec_discarded": s_pin.spec_discarded,
-                     "max_in_flight": s_pin.max_in_flight,
-                     "pod_max_in_flight": s_pod.max_in_flight},
-        "centers_equal": centers_equal, "fitness_equal": fitness_equal,
-        "pipelined_parity_ok": pipelined_ok, "pod_parity_ok": pod_ok,
-        "parity_ok": ok,
-    }
-    path = os.path.join(out_dir, "substrate_pod_mesh.json")
-    with open(path, "w") as f:
-        json.dump(report, f, indent=2)
-    print(f"[{'ok' if ok else 'FAIL'}] substrate pod_mesh: "
-          f"{pod.n_shards} data shards, iters "
-          f"{e_in.iteration}/{e_pin.iteration}/{e_pod.iteration}, final "
-          f"{e_in.best_fitness:.6f}/{e_pin.best_fitness:.6f}/"
-          f"{e_pod.best_fitness:.6f}, wall {t_in:.2f}s/{t_pin:.2f}s/"
-          f"{t_pod:.2f}s (sync/pipelined/pod-pipelined) -> {path}")
-    return ok
-
-
-def run_multi_search_smoke(out_dir: str, n_searches: int = 4, m: int = 24,
-                           iterations: int = 2, n_stars: int = 400,
-                           fleet_hosts: int = 512) -> bool:
-    """Multi-search orchestrator smoke (``--substrate multi_search``).
-
-    A heterogeneous ``n_searches``-way portfolio (two different per-phase
-    ``m``'s, perturbed starts, per-slot sub-fleets) runs coalesced over
-    one shared backend, twice: through ``InProcessEvalBackend`` and
-    through ``PodMeshEvalBackend`` on the production (data=16, model=16)
-    mesh of forced host devices.  For EVERY search and BOTH backends, the
-    orchestrated engine must commit bit-identical iterates and identical
-    final stats to the same spec run alone on the same backend — the
-    coalescing-safety contract of DESIGN.md §8.  Writes
-    artifacts/dryrun/substrate_multi_search.json; returns pass/fail.
-    """
-    import numpy as np
-    from repro.core.anm import AnmConfig
-    from repro.core.engine import identical_trajectories
-    from repro.core.grid import GridConfig
-    from repro.core.orchestrator import (FleetScheduler, SearchDirector,
-                                         multi_start_specs)
-    from repro.core.substrates.eval_backend import InProcessEvalBackend
-    from repro.core.substrates.pod_mesh import PodMeshEvalBackend
-    from repro.data import sdss
-
-    mesh = make_production_mesh()
-    stripe = sdss.make_stripe("multisearch_smoke", n_stars=n_stars, seed=23)
-    f_batch, _ = sdss.make_fitness(stripe)
-    rng = np.random.default_rng(3)
-    x0 = np.clip(stripe.truth + rng.normal(0, 0.2, 8).astype(np.float32),
-                 sdss.LO, sdss.HI)
-    fleet = GridConfig(n_hosts=fleet_hosts, failure_prob=0.05,
-                       malicious_prob=0.01, seed=9)
-    configs = [AnmConfig(m_regression=m, m_line_search=m,
-                         max_iterations=iterations),
-               AnmConfig(m_regression=m // 2, m_line_search=m // 2,
-                         max_iterations=iterations)]
-
-    def run_portfolio(backend):
-        sched = FleetScheduler(backend, fleet)
-        specs = multi_start_specs(sched, x0, sdss.LO, sdss.HI,
-                                  sdss.DEFAULT_STEP, configs[0], n_searches,
-                                  seed=7, jitter=0.3, configs=configs)
-        t0 = time.time()
-        res = SearchDirector(sched, specs).run()
-        wall = time.time() - t0
-        parity = []
-        for o in res.outcomes:
-            solo = o.spec.solo_run(backend)
-            parity.append(identical_trajectories(o.engine, solo)
-                          and o.engine.stats == solo.stats)
-        return res, wall, parity
-
-    backends = {
-        "in_process": InProcessEvalBackend(f_batch),
-        "pod_mesh": PodMeshEvalBackend(f_batch, mesh=mesh),
-    }
-    report = {"mesh": "16x16", "n_searches": n_searches,
-              "fleet_hosts": fleet_hosts, "backends": {}}
-    ok = True
-    cross = {}
-    for name, backend in backends.items():
-        res, wall, parity = run_portfolio(backend)
-        co = res.coalesce_stats
-        report["backends"][name] = {
-            "parity_per_search": parity,
-            "iterations": [o.engine.iteration for o in res.outcomes],
-            "final": [o.engine.best_fitness for o in res.outcomes],
-            "rounds": res.rounds,
-            "dispatches": co.dispatches, "lane_blocks": co.lane_blocks,
-            "padded_lanes": co.padded_lanes,
-            "solo_padded_lanes": co.solo_padded_lanes,
-            "wall_s": round(wall, 3),
-        }
-        cross[name] = res
-        ok = ok and all(parity)
-    # row-independence also means the portfolio itself must agree across
-    # backends, search by search
-    backend_pair_ok = all(
-        identical_trajectories(a.engine, b.engine)
-        for a, b in zip(cross["in_process"].outcomes,
-                        cross["pod_mesh"].outcomes))
-    ok = ok and backend_pair_ok
-    report["cross_backend_ok"] = backend_pair_ok
-    report["parity_ok"] = ok
-    path = os.path.join(out_dir, "substrate_multi_search.json")
-    with open(path, "w") as f:
-        json.dump(report, f, indent=2)
-    rb = report["backends"]
-    print(f"[{'ok' if ok else 'FAIL'}] substrate multi_search: "
-          f"{n_searches} searches, dispatches "
-          f"{rb['in_process']['dispatches']}/{rb['pod_mesh']['dispatches']} "
-          f"for {rb['in_process']['lane_blocks']} blocks, wall "
-          f"{rb['in_process']['wall_s']}s/{rb['pod_mesh']['wall_s']}s "
-          f"(in-process/pod), cross-backend "
-          f"{'ok' if backend_pair_ok else 'FAIL'} -> {path}")
-    return ok
-
-
-def run_cached_portfolio_smoke(out_dir: str, n_searches: int = 8,
-                               m: int = 24, iterations: int = 2,
-                               n_stars: int = 400,
-                               fleet_hosts: int = 512) -> bool:
-    """Eval-cache smoke (``--substrate cached_portfolio``).
-
-    An ``n_searches``-way coalesced portfolio runs three times per
-    backend (``InProcessEvalBackend`` and ``PodMeshEvalBackend`` on the
-    production mesh): cache-off, cache-on cold, and cache-on warm (same
-    cache, whole portfolio replayed).  The §10 gates:
-
-      * bit-exact parity — both cache-on runs commit bit-identical
-        iterates and identical final stats to cache-off, per search;
-      * the warm rerun is FULLY served — zero new misses, hits > 0
-        (only malicious lanes touch the device again).
-
-    Writes artifacts/dryrun/substrate_cached_portfolio.json.
-    """
-    import numpy as np
-    from repro.core.anm import AnmConfig
-    from repro.core.engine import identical_trajectories
-    from repro.core.grid import GridConfig
-    from repro.core.orchestrator import (FleetScheduler, SearchDirector,
-                                         multi_start_specs)
-    from repro.core.substrates.eval_backend import InProcessEvalBackend
-    from repro.core.substrates.eval_cache import EvalCache
-    from repro.core.substrates.pod_mesh import PodMeshEvalBackend
-    from repro.data import sdss
-
-    mesh = make_production_mesh()
-    stripe = sdss.make_stripe("cached_portfolio_smoke", n_stars=n_stars,
-                              seed=23)
-    f_batch, _ = sdss.make_fitness(stripe)
-    rng = np.random.default_rng(3)
-    x0 = np.clip(stripe.truth + rng.normal(0, 0.2, 8).astype(np.float32),
-                 sdss.LO, sdss.HI)
-    fleet = GridConfig(n_hosts=fleet_hosts, failure_prob=0.05,
-                       malicious_prob=0.01, seed=9)
-    anm = AnmConfig(m_regression=m, m_line_search=m,
-                    max_iterations=iterations)
-
-    def portfolio(backend, cache):
-        sched = FleetScheduler(backend, fleet, cache=cache)
-        specs = multi_start_specs(sched, x0, sdss.LO, sdss.HI,
-                                  sdss.DEFAULT_STEP, anm, n_searches,
-                                  seed=7, jitter=0.3)
-        t0 = time.time()
-        res = SearchDirector(sched, specs).run()
-        return res, time.time() - t0
-
-    def pairwise_identical(a, b):
-        return all(identical_trajectories(x.engine, y.engine)
-                   and x.engine.stats == y.engine.stats
-                   for x, y in zip(a.outcomes, b.outcomes))
-
-    backends = {
-        "in_process": InProcessEvalBackend(f_batch),
-        "pod_mesh": PodMeshEvalBackend(f_batch, mesh=mesh),
-    }
-    report = {"mesh": "16x16", "n_searches": n_searches,
-              "fleet_hosts": fleet_hosts, "backends": {}}
-    ok = True
-    for name, backend in backends.items():
-        off, wall_off = portfolio(backend, None)
-        cache = EvalCache(fingerprint=f"cached_portfolio/{name}")
-        cold, wall_cold = portfolio(backend, cache)
-        misses0 = cache.stats.misses
-        hits0 = cache.stats.hits
-        warm, wall_warm = portfolio(backend, cache)
-        cold_parity = pairwise_identical(off, cold)
-        warm_parity = pairwise_identical(off, warm)
-        warm_served = (cache.stats.misses == misses0
-                       and cache.stats.hits > hits0)
-        b_ok = cold_parity and warm_parity and warm_served
-        report["backends"][name] = {
-            "cold_parity": cold_parity, "warm_parity": warm_parity,
-            "warm_fully_served": warm_served,
-            "cache": cache.status(),
-            "lanes_deduped": (warm.coalesce_stats.lanes_deduped
-                              if warm.coalesce_stats else 0),
-            "wall_s": {"off": round(wall_off, 3),
-                       "cold": round(wall_cold, 3),
-                       "warm": round(wall_warm, 3)},
-        }
-        ok = ok and b_ok
-    report["parity_ok"] = ok
-    path = os.path.join(out_dir, "substrate_cached_portfolio.json")
-    with open(path, "w") as f:
-        json.dump(report, f, indent=2)
-    rb = report["backends"]
-    ip = rb["in_process"]
-    print(f"[{'ok' if ok else 'FAIL'}] substrate cached_portfolio: "
-          f"{n_searches} searches, hit_rate "
-          f"{ip['cache']['hit_rate']:.2f}, wall off/cold/warm "
-          f"{ip['wall_s']['off']}s/{ip['wall_s']['cold']}s/"
-          f"{ip['wall_s']['warm']}s (in-process), pod warm_parity "
-          f"{rb['pod_mesh']['warm_parity']} -> {path}")
-    return ok
-
-
-def run_lm_subspace_smoke(out_dir: str, arch: str = "rwkv6-7b",
-                          k: int = 6, m: int = 12, iterations: int = 2,
-                          n_hosts: int = 48) -> bool:
-    """LM-loss workload smoke (``--substrate lm_subspace``).
-
-    The model stack IS the fitness function: an ``LmWorkload`` over one
-    smoke config (kernels routed through ``kernels/ops.py``), searched in
-    its k-dim subspace-coefficient box by the full asynchronous stack.
-    Gates (DESIGN.md §11):
-
-      1. sync == pipelined == pod: the batched grid commits bit-identical
-         iterates through the in-process backend (both tick loops) and
-         through the pod backend — lanes sharded over ``data``, θ0 and
-         the basis STORED sharded over ``model`` on the production 16×16
-         mesh — with ZERO compiles once warmed;
-      2. orchestrator + cache: a coalesced 2-search portfolio over the
-         shared backend, evaluated through ``CachingSubmitter``; every
-         search bit-identical to its solo run, warm replay fully served;
-      3. work server: the same workload through the crash-recoverable
-         server (simulated crash mid-run, restore from snapshot + replay
-         log) — restored == uninterrupted, and in-process == pod through
-         the whole server stack.
-
-    Writes artifacts/dryrun/substrate_lm_subspace.json; returns pass/fail.
-    """
-    import numpy as np
-    from repro.core.engine import identical_trajectories
-    from repro.core.orchestrator import (FleetScheduler, SearchDirector,
-                                         multi_start_specs)
-    from repro.core.substrates.batched_grid import BatchedVolunteerGrid
-    from repro.core.substrates.eval_backend import bucket_size
-    from repro.core.substrates.eval_cache import EvalCache
-    from repro.core.substrates.lm_loss import LmLossEvalBackend
-    from repro.server.sim import (ServerSubstrate, SimulatedCrash,
-                                  lm_problem, result_doc)
-
-    mesh = make_production_mesh()
-    spec, fleet, wl = lm_problem(arch=arch, k=k, n_hosts=n_hosts, m=m,
-                                 iterations=iterations)
-    max_bucket = bucket_size(BatchedVolunteerGrid.warm_max_bucket(m))
-    t0 = time.time()
-    in_backend = LmLossEvalBackend(wl, n_dims=k, max_bucket=max_bucket)
-    pod = LmLossEvalBackend(wl, mesh=mesh, n_dims=k, max_bucket=max_bucket)
-    t_warm = time.time() - t0
-    compiles_warm = (in_backend.compile_count, pod.compile_count)
-
-    # -- gate 1: sync == pipelined == pod, zero compiles after warm --------
-    def grid_run(backend, pipelined):
-        engine = spec.build_engine()
-        t0 = time.time()
-        stats = BatchedVolunteerGrid(None, spec.grid, backend=backend,
-                                     pipelined=pipelined).run(engine)
-        return engine, stats, time.time() - t0
-
-    e_sync, s_sync, t_sync = grid_run(in_backend, False)
-    e_pipe, s_pipe, t_pipe = grid_run(in_backend, True)
-    e_pod, s_pod, t_pod = grid_run(pod, True)
-    pipe_ok = identical_trajectories(e_sync, e_pipe)
-    pod_ok = identical_trajectories(e_sync, e_pod)
-    zero_compiles = (in_backend.compile_count == compiles_warm[0]
-                     and pod.compile_count == compiles_warm[1])
-
-    # -- gate 2: coalesced portfolio through CachingSubmitter --------------
-    cache = EvalCache(fingerprint=f"lm_subspace/{arch}/{k}")
-    def portfolio():
-        sched = FleetScheduler(in_backend, fleet, cache=cache)
-        specs = multi_start_specs(sched, spec.x0, spec.lo, spec.hi,
-                                  spec.step, spec.anm, 2, seed=7,
-                                  jitter=0.3)
-        return SearchDirector(sched, specs).run()
-
-    t0 = time.time()
-    cold = portfolio()
-    misses0, hits0 = cache.stats.misses, cache.stats.hits
-    warm = portfolio()
-    t_port = time.time() - t0
-    solo_parity = [identical_trajectories(o.engine,
-                                          o.spec.solo_run(in_backend))
-                   for o in cold.outcomes]
-    warm_parity = all(identical_trajectories(a.engine, b.engine)
-                      for a, b in zip(cold.outcomes, warm.outcomes))
-    warm_served = (cache.stats.misses == misses0
-                   and cache.stats.hits > hits0)
-    orch_ok = all(solo_parity) and warm_parity and warm_served
-
-    # -- gate 3: the crash-recoverable work server -------------------------
-    import tempfile
-    t0 = time.time()
-    base_doc = result_doc(ServerSubstrate(spec, fleet, in_backend).run())
-    pod_doc = result_doc(ServerSubstrate(spec, fleet, pod).run())
-    server_backend_ok = (
-        base_doc["history"] == pod_doc["history"]
-        and base_doc["engine_stats"] == pod_doc["engine_stats"])
-    kill_after = max(50, int(0.4 * base_doc["pool"]["messages"]))
-    with tempfile.TemporaryDirectory(prefix="lm_server_") as ckpt:
-        try:
-            ServerSubstrate(spec, fleet, in_backend, ckpt_dir=ckpt,
-                            snapshot_every=25,
-                            max_messages=kill_after).run()
-            crashed = False            # finished before the crash: fail
-        except SimulatedCrash:
-            crashed = True
-        resumed = ServerSubstrate(spec, fleet, in_backend,
-                                  ckpt_dir=ckpt).run(resume=True)
-    res_doc = result_doc(resumed)
-    restore_ok = (crashed and not res_doc["recovered_done"]
-                  and res_doc["history"] == base_doc["history"]
-                  and res_doc["engine_stats"] == base_doc["engine_stats"])
-    t_server = time.time() - t0
-
-    ok = (pipe_ok and pod_ok and zero_compiles and orch_ok
-          and server_backend_ok and restore_ok)
-    report = {
-        "arch": arch, "k": k, "m": m, "iterations": iterations,
-        "mesh": "16x16", "n_params": int(wl.proj.n_params),
-        "data_shards": pod.n_shards, "min_bucket": pod.min_bucket,
-        "model_spec_fallbacks": len(pod.spec_fallbacks),
-        "warm_s": round(t_warm, 3),
-        "compiles": {"in_process": in_backend.compile_count,
-                     "pod": pod.compile_count,
-                     "zero_after_warm": zero_compiles},
-        "grid": {
-            "iterations": {"sync": e_sync.iteration,
-                           "pipelined": e_pipe.iteration,
-                           "pod": e_pod.iteration},
-            "final": {"sync": e_sync.best_fitness,
-                      "pipelined": e_pipe.best_fitness,
-                      "pod": e_pod.best_fitness},
-            "batch_calls": {"sync": s_sync.batch_calls,
-                            "pipelined": s_pipe.batch_calls,
-                            "pod": s_pod.batch_calls},
-            "wall_s": {"sync": round(t_sync, 3),
-                       "pipelined": round(t_pipe, 3),
-                       "pod": round(t_pod, 3)},
-            "pipelined_parity_ok": pipe_ok, "pod_parity_ok": pod_ok,
-        },
-        "orchestrator": {
-            "solo_parity": solo_parity, "warm_replay_parity": warm_parity,
-            "warm_fully_served": warm_served, "cache": cache.status(),
-            "wall_s": round(t_port, 3), "parity_ok": orch_ok,
-        },
-        "server": {
-            "iterations": base_doc["iteration"],
-            "best": base_doc["best_fitness"],
-            "messages": base_doc["pool"]["messages"],
-            "backend_parity_ok": server_backend_ok,
-            "crashed_mid_run": crashed,
-            "replayed": res_doc["replayed"],
-            "resumed_leases": res_doc["pool"]["resumed_leases"],
-            "restore_parity_ok": restore_ok,
-            "wall_s": round(t_server, 3),
-        },
-        "parity_ok": ok,
-    }
-    path = os.path.join(out_dir, "substrate_lm_subspace.json")
-    with open(path, "w") as f:
-        json.dump(report, f, indent=2)
-    print(f"[{'ok' if ok else 'FAIL'}] substrate lm_subspace: {arch} "
-          f"({wl.proj.n_params} params, k={k}), grid "
-          f"{'ok' if pipe_ok and pod_ok else 'FAIL'} "
-          f"(wall {t_sync:.1f}s/{t_pipe:.1f}s/{t_pod:.1f}s "
-          f"sync/pipelined/pod), compiles "
-          f"{'0' if zero_compiles else 'NONZERO'} after warm, "
-          f"orchestrator {'ok' if orch_ok else 'FAIL'}, server "
-          f"{'ok' if server_backend_ok and restore_ok else 'FAIL'} "
-          f"-> {path}")
-    return ok
-
-
-def run_server_smoke(out_dir: str, n_hosts: int = 160, m: int = 24,
-                     iterations: int = 4, n_stars: int = 400) -> bool:
-    """Service-layer kill/restore smoke (``--substrate server``).
-
-    The seeded smoke search (``repro.server.sim.smoke_problem``) runs four
-    ways, every subprocess with a CLEAN single-device CPU environment (the
-    dryrun's forced 512-device platform stays in THIS process):
-
-      1. uninterrupted, loopback transport                → the baseline;
-      2. uninterrupted, pod-mesh evaluation path          → must equal 1
-         (row-independence across evaluation widths, DESIGN.md §6/§8) —
-         plus an IN-PROCESS run over the production 16×16 mesh here in
-         the parent, exercising the real partitioning;
-      3. SIGKILLed mid-search on loopback, restored from snapshot +
-         replay log, run to completion                    → must equal 1;
-      4. the same kill/restore over the TCP transport     → must equal 1;
-      5. the same loopback kill/restore with ``--cache``  → must equal 1,
-         AND the restored process must come back WARM: its eval-cache
-         store survives the SIGKILL in the checkpoint dir and serves the
-         re-leased in-flight points (``cache.hits > 0``, DESIGN.md §10).
-
-    "Equal" is the hard service-layer contract: bit-identical committed
-    centers and fitness history AND identical final ``EngineStats``.
-    Writes artifacts/dryrun/substrate_server.json; returns pass/fail.
-    """
-    import shutil
-    import signal
-    import subprocess
-    import sys
-    import tempfile
-
-    child_env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    child_env["JAX_PLATFORMS"] = "cpu"
-    src_dir = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                           ".."))
-    child_env["PYTHONPATH"] = src_dir + (
-        ":" + child_env["PYTHONPATH"] if child_env.get("PYTHONPATH") else "")
-    spec_args = ["--n-hosts", str(n_hosts), "--m", str(m),
-                 "--iterations", str(iterations), "--n-stars", str(n_stars)]
-
-    def child(extra, timeout=600):
-        cmd = [sys.executable, "-m", "repro.server.sim"] + spec_args + extra
-        return subprocess.run(cmd, env=child_env, timeout=timeout,
-                              capture_output=True, text=True)
-
-    def load(path):
-        with open(path) as f:
-            return json.load(f)
-
-    def trajectories_equal(a, b):
-        return (a["history"] == b["history"]
-                and a["iteration"] == b["iteration"]
-                and a["best_fitness"] == b["best_fitness"]
-                and a["engine_stats"] == b["engine_stats"])
-
-    tmp = tempfile.mkdtemp(prefix="server_smoke_")
-    report = {"n_hosts": n_hosts, "m": m, "iterations": iterations}
-    ok = True
-    try:
-        # 1+2: uninterrupted baselines on both evaluation paths
-        base_path = os.path.join(tmp, "base.json")
-        r = child(["--out", base_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("baseline child failed")
-        base = load(base_path)
-        pod_path = os.path.join(tmp, "pod.json")
-        r = child(["--backend", "pod_mesh", "--out", pod_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("pod-backend child failed")
-        pod = load(pod_path)
-        backend_ok = trajectories_equal(base, pod)
-        # ... and the REAL 16x16 partitioning, in-parent on the forced
-        # 512-device platform (the whole point of the dryrun environment)
-        from repro.core.substrates.pod_mesh import PodMeshEvalBackend
-        from repro.server.sim import (ServerSubstrate, result_doc,
-                                      smoke_problem)
-        spec, fleet, f_batch = smoke_problem(
-            n_stars=n_stars, n_hosts=n_hosts, m=m, iterations=iterations)
-        mesh_backend = PodMeshEvalBackend(f_batch,
-                                          mesh=make_production_mesh())
-        mesh_doc = result_doc(
-            ServerSubstrate(spec, fleet, mesh_backend).run())
-        mesh_ok = trajectories_equal(base, mesh_doc)
-
-        # 3+4+5: SIGKILL mid-search, restore, compare — both transports,
-        # then loopback again with the persistent eval cache enabled
-        kills = {}
-        variants = (("loopback", "loopback", []),
-                    ("tcp", "tcp", []),
-                    ("loopback_cache", "loopback", ["--cache"]))
-        for variant, transport, cache_args in variants:
-            ckpt = os.path.join(tmp, f"ckpt_{variant}")
-            proc = subprocess.Popen(
-                [sys.executable, "-m", "repro.server.sim", *spec_args,
-                 "--transport", transport, "--ckpt-dir", ckpt,
-                 "--snapshot-every", "200", "--throttle-s", "0.002",
-                 *cache_args],
-                env=child_env, stdout=subprocess.PIPE,
-                stderr=subprocess.PIPE)
-            log_path = os.path.join(ckpt, "replay.jsonl")
-            deadline = time.time() + 300
-            killed_mid_run = False
-            # kill once ~40% of the baseline's message count has been
-            # logged: deep enough that the kill lands well past the
-            # bootstrap, with most of the run still ahead (the throttle
-            # in the child stretches the wall-clock window so the 20 ms
-            # poll cannot miss it)
-            kill_after = max(200, int(0.4 * base["pool"]["messages"]))
-            while time.time() < deadline:
-                if proc.poll() is not None:
-                    break             # finished before we could kill: fail
-                has_snap = os.path.isdir(ckpt) and any(
-                    f.startswith("snapshot_") for f in os.listdir(ckpt))
-                log_lines = 0
-                if os.path.exists(log_path):
-                    with open(log_path, "rb") as f:
-                        log_lines = f.read().count(b"\n")
-                if has_snap and log_lines >= kill_after:
-                    proc.send_signal(signal.SIGKILL)
-                    proc.wait(timeout=30)
-                    killed_mid_run = True
-                    break
-                time.sleep(0.02)
-            if not killed_mid_run:
-                proc.kill()
-                kills[variant] = {"killed_mid_run": False, "ok": False}
-                ok = False
-                continue
-            out_path = os.path.join(tmp, f"resume_{variant}.json")
-            r = child(["--transport", transport, "--ckpt-dir", ckpt,
-                       "--resume", "--out", out_path, *cache_args])
-            if r.returncode != 0:
-                print(r.stdout + r.stderr)
-                kills[variant] = {"killed_mid_run": True, "ok": False,
-                                  "error": "resume child failed"}
-                ok = False
-                continue
-            res = load(out_path)
-            t_ok = (trajectories_equal(base, res)
-                    and not res["recovered_done"])
-            kills[variant] = {
-                "killed_mid_run": True,
-                "recovered_done": res["recovered_done"],
-                "replayed": res["replayed"],
-                "resumed_leases": res["pool"]["resumed_leases"],
-                "trajectory_equal": trajectories_equal(base, res),
-                "ok": t_ok,
-            }
-            if cache_args:
-                # the §10 warm-restore gate: the store survived the kill
-                # and the restored process actually served from it
-                warm = (res["cache"] is not None
-                        and res["cache"]["hits"] > 0
-                        and res["cache"]["store_size"] > 0)
-                kills[variant]["cache"] = res["cache"]
-                kills[variant]["warm_after_restore"] = warm
-                kills[variant]["ok"] = t_ok = t_ok and warm
-            ok = ok and t_ok
-        report.update({
-            "baseline": {"iterations": base["iteration"],
-                         "best": base["best_fitness"],
-                         "messages": base["pool"]["messages"],
-                         "registry": base["registry"]},
-            "backend_parity_ok": backend_ok,
-            "production_mesh_parity_ok": mesh_ok,
-            "kill_restore": kills,
-        })
-        ok = ok and backend_ok and mesh_ok
-    except Exception as e:  # noqa: BLE001 — smoke must report, not die
-        report["error"] = str(e)
-        ok = False
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    report["parity_ok"] = ok
-    path = os.path.join(out_dir, "substrate_server.json")
-    with open(path, "w") as f:
-        json.dump(report, f, indent=2)
-    kr = report.get("kill_restore", {})
-    print(f"[{'ok' if ok else 'FAIL'}] substrate server: "
-          f"backend_parity={report.get('backend_parity_ok')} "
-          f"mesh_parity={report.get('production_mesh_parity_ok')} "
-          f"loopback_kill={kr.get('loopback', {}).get('ok')} "
-          f"tcp_kill={kr.get('tcp', {}).get('ok')} "
-          f"cache_kill={kr.get('loopback_cache', {}).get('ok')} "
-          f"warm={kr.get('loopback_cache', {}).get('warm_after_restore')} "
-          f"-> {path}")
-    return ok
-
-
-def run_chaos_server_smoke(out_dir: str, n_hosts: int = 48, m: int = 12,
-                           iterations: int = 3, n_stars: int = 200,
-                           n_clients: int = 8) -> bool:
-    """Chaos-hardened work-service smoke (``--substrate chaos_server``,
-    DESIGN.md §12).
-
-    The seeded smoke search runs once serially on loopback with no faults
-    (the baseline), then repeatedly as ``n_clients`` truly concurrent TCP
-    clients — clean, and under each of three seeded ``FaultPlan`` presets
-    (drops + duplication, reordering delay, resets + torn writes) — every
-    time in a clean single-device CPU subprocess.  The hard gate is the
-    tentpole contract: bit-identical committed iterates and identical
-    final engine stats vs the fault-free serial baseline, with the fault
-    counters proving the schedule actually injected.  Two more legs:
-
-      * a SIGKILL mid-chaos (concurrent TCP + reset_torn), restored from
-        snapshot + replay log and run to completion → must equal the
-        baseline;
-      * an in-parent concurrent+chaos run evaluating through the REAL
-        16×16 production-mesh backend on the forced 512-device platform —
-        fault tolerance and the production partitioning composed.
-
-    Writes artifacts/dryrun/substrate_chaos_server.json; returns pass/fail.
-    """
-    import shutil
-    import signal
-    import subprocess
-    import sys
-    import tempfile
-
-    child_env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    child_env["JAX_PLATFORMS"] = "cpu"
-    src_dir = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                           ".."))
-    child_env["PYTHONPATH"] = src_dir + (
-        ":" + child_env["PYTHONPATH"] if child_env.get("PYTHONPATH") else "")
-    spec_args = ["--n-hosts", str(n_hosts), "--m", str(m),
-                 "--iterations", str(iterations), "--n-stars", str(n_stars)]
-    conc_args = ["--transport", "tcp", "--concurrent", str(n_clients)]
-
-    def child(extra, timeout=600):
-        cmd = [sys.executable, "-m", "repro.server.sim"] + spec_args + extra
-        return subprocess.run(cmd, env=child_env, timeout=timeout,
-                              capture_output=True, text=True)
-
-    def load(path):
-        with open(path) as f:
-            return json.load(f)
-
-    def trajectories_equal(a, b):
-        return (a["history"] == b["history"]
-                and a["iteration"] == b["iteration"]
-                and a["best_fitness"] == b["best_fitness"]
-                and a["engine_stats"] == b["engine_stats"])
-
-    tmp = tempfile.mkdtemp(prefix="chaos_smoke_")
-    report = {"n_hosts": n_hosts, "m": m, "iterations": iterations,
-              "n_clients": n_clients}
-    ok = True
-    try:
-        base_path = os.path.join(tmp, "base.json")
-        r = child(["--out", base_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("serial baseline child failed")
-        base = load(base_path)
-
-        # clean concurrency first: the intake + release machinery alone
-        clean_path = os.path.join(tmp, "concurrent.json")
-        r = child([*conc_args, "--out", clean_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("concurrent clean child failed")
-        clean = load(clean_path)
-        concurrent_ok = (trajectories_equal(base, clean)
-                         and clean["intake"]["parked"] > 0)
-        report["concurrent_clean"] = {
-            "trajectory_equal": trajectories_equal(base, clean),
-            "intake": clean["intake"], "ok": concurrent_ok}
-
-        # the three seeded fault schedules
-        plans = {}
-        for preset in ("drop_dup", "reorder_delay", "reset_torn"):
-            p_path = os.path.join(tmp, f"{preset}.json")
-            r = child([*conc_args, "--chaos", preset, "--out", p_path])
-            if r.returncode != 0:
-                print(r.stdout + r.stderr)
-                plans[preset] = {"ok": False, "error": "child failed"}
-                ok = False
-                continue
-            doc = load(p_path)
-            ch = doc["chaos"]
-            injected = (ch["drops_request"] + ch["drops_reply"]
-                        + ch["duplicates"] + ch["delays"] + ch["resets"]
-                        + ch["torn_writes"])
-            p_ok = trajectories_equal(base, doc) and injected > 0
-            plans[preset] = {
-                "trajectory_equal": trajectories_equal(base, doc),
-                "faults_injected": injected,
-                "chaos": {k: v for k, v in ch.items() if k != "plan"},
-                "ok": p_ok}
-            ok = ok and p_ok
-        report["fault_plans"] = plans
-
-        # SIGKILL mid-chaos + restore under the same plan
-        ckpt = os.path.join(tmp, "ckpt_chaos")
-        kill_args = [*conc_args, "--chaos", "reset_torn", "--ckpt-dir",
-                     ckpt, "--snapshot-every", "150", "--throttle-s",
-                     "0.002"]
-        proc = subprocess.Popen(
-            [sys.executable, "-m", "repro.server.sim", *spec_args,
-             *kill_args],
-            env=child_env, stdout=subprocess.PIPE, stderr=subprocess.PIPE)
-        log_path = os.path.join(ckpt, "replay.jsonl")
-        deadline = time.time() + 300
-        killed_mid_run = False
-        kill_after = max(150, int(0.4 * base["pool"]["messages"]))
-        while time.time() < deadline:
-            if proc.poll() is not None:
-                break
-            has_snap = os.path.isdir(ckpt) and any(
-                f.startswith("snapshot_") for f in os.listdir(ckpt))
-            log_lines = 0
-            if os.path.exists(log_path):
-                with open(log_path, "rb") as f:
-                    log_lines = f.read().count(b"\n")
-            if has_snap and log_lines >= kill_after:
-                proc.send_signal(signal.SIGKILL)
-                proc.wait(timeout=30)
-                killed_mid_run = True
-                break
-            time.sleep(0.02)
-        if not killed_mid_run:
-            proc.kill()
-            report["kill_restore"] = {"killed_mid_run": False, "ok": False}
-            ok = False
-        else:
-            out_path = os.path.join(tmp, "resume_chaos.json")
-            r = child([*kill_args, "--resume", "--out", out_path])
-            if r.returncode != 0:
-                print(r.stdout + r.stderr)
-                report["kill_restore"] = {"killed_mid_run": True,
-                                          "ok": False,
-                                          "error": "resume child failed"}
-                ok = False
-            else:
-                res = load(out_path)
-                k_ok = (trajectories_equal(base, res)
-                        and not res["recovered_done"])
-                report["kill_restore"] = {
-                    "killed_mid_run": True,
-                    "recovered_done": res["recovered_done"],
-                    "replayed": res["replayed"],
-                    "resumed_leases": res["pool"]["resumed_leases"],
-                    "trajectory_equal": trajectories_equal(base, res),
-                    "ok": k_ok}
-                ok = ok and k_ok
-
-        # in-parent: concurrent + chaos over the REAL production mesh on
-        # the forced 512-device platform
-        from repro.core.substrates.pod_mesh import PodMeshEvalBackend
-        from repro.server.sim import (ServerSubstrate, result_doc,
-                                      smoke_problem)
-        spec, fleet, f_batch = smoke_problem(
-            n_stars=n_stars, n_hosts=n_hosts, m=m, iterations=iterations)
-        mesh_backend = PodMeshEvalBackend(f_batch,
-                                          mesh=make_production_mesh())
-        mesh_doc = result_doc(ServerSubstrate(
-            spec, fleet, mesh_backend, transport="tcp",
-            concurrent=n_clients, chaos="drop_dup").run())
-        mesh_ok = trajectories_equal(base, mesh_doc)
-        report["production_mesh_chaos"] = {
-            "trajectory_equal": mesh_ok,
-            "chaos": {k: v for k, v in mesh_doc["chaos"].items()
-                      if k != "plan"},
-            "ok": mesh_ok}
-        ok = ok and concurrent_ok and mesh_ok
-    except Exception as e:  # noqa: BLE001 — smoke must report, not die
-        report["error"] = str(e)
-        ok = False
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    report["parity_ok"] = ok
-    path = os.path.join(out_dir, "substrate_chaos_server.json")
-    with open(path, "w") as f:
-        json.dump(report, f, indent=2)
-    fp = report.get("fault_plans", {})
-    print(f"[{'ok' if ok else 'FAIL'}] substrate chaos_server: "
-          f"concurrent={report.get('concurrent_clean', {}).get('ok')} "
-          f"drop_dup={fp.get('drop_dup', {}).get('ok')} "
-          f"reorder={fp.get('reorder_delay', {}).get('ok')} "
-          f"reset_torn={fp.get('reset_torn', {}).get('ok')} "
-          f"kill={report.get('kill_restore', {}).get('ok')} "
-          f"mesh={report.get('production_mesh_chaos', {}).get('ok')} "
-          f"-> {path}")
-    return ok
-
-
-def run_obs_server_smoke(out_dir: str, n_hosts: int = 48, m: int = 12,
-                         iterations: int = 3, n_stars: int = 200,
-                         n_clients: int = 8) -> bool:
-    """Observability-plane smoke (``--substrate obs_server``, DESIGN.md
-    §13).
-
-    Every leg shares one injected fleet failure — a quarter of the host
-    ids go silent at virtual time 150 — so the anomaly machinery always
-    has churn to see, and every parity pair lives in the same world:
-
-      1. the UNOBSERVED serial loopback baseline;
-      2. observed live: metrics hub + ``n_clients`` truly concurrent TCP
-         clients + a real background ``subscribe_stats`` subscriber
-         polling over its own socket during the run → bit-identical to 1,
-         and the subscriber must have received ≥ 2 stamped snapshots with
-         strictly increasing seqs;
-      3. observed under chaos (``drop_dup`` fault plan, concurrent TCP) →
-         bit-identical to 1 with faults provably injected (monitoring
-         traffic bypasses the injector, so the fault schedule — keyed on
-         stamped client messages — is unchanged);
-      4. observed + subscribed, SIGKILLed mid-stream on loopback,
-         restored from snapshot + replay log with obs re-attached →
-         bit-identical to 1 (the hub owns no replayable state);
-      5. anomaly defense live: detectors quarantine the silenced cohort
-         out of the registry's reliable set (measurably smaller than the
-         undefended baseline's), recording the verdict schedule — then a
-         REPLAY run applies the recorded schedule with detectors off and
-         must reproduce the defended trajectory bit-for-bit.
-
-    Writes artifacts/dryrun/substrate_obs_server.json; returns pass/fail.
-    """
-    import shutil
-    import signal
-    import subprocess
-    import sys
-    import tempfile
-
-    child_env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    child_env["JAX_PLATFORMS"] = "cpu"
-    src_dir = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                           ".."))
-    child_env["PYTHONPATH"] = src_dir + (
-        ":" + child_env["PYTHONPATH"] if child_env.get("PYTHONPATH") else "")
-    spec_args = ["--n-hosts", str(n_hosts), "--m", str(m),
-                 "--iterations", str(iterations), "--n-stars", str(n_stars),
-                 "--silence-at", "150", "--silence-frac", "0.25"]
-    obs_args = ["--obs", "--stats-interval", "10"]
-    conc_args = ["--transport", "tcp", "--concurrent", str(n_clients)]
-
-    def child(extra, timeout=600):
-        cmd = [sys.executable, "-m", "repro.server.sim"] + spec_args + extra
-        return subprocess.run(cmd, env=child_env, timeout=timeout,
-                              capture_output=True, text=True)
-
-    def load(path):
-        with open(path) as f:
-            return json.load(f)
-
-    def trajectories_equal(a, b):
-        return (a["history"] == b["history"]
-                and a["iteration"] == b["iteration"]
-                and a["best_fitness"] == b["best_fitness"]
-                and a["engine_stats"] == b["engine_stats"])
-
-    tmp = tempfile.mkdtemp(prefix="obs_smoke_")
-    report = {"n_hosts": n_hosts, "m": m, "iterations": iterations,
-              "n_clients": n_clients, "silence_at": 150.0,
-              "silence_frac": 0.25}
-    ok = True
-    try:
-        # 1: the unobserved baseline (same silenced world as every leg)
-        base_path = os.path.join(tmp, "base.json")
-        r = child(["--out", base_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("unobserved baseline child failed")
-        base = load(base_path)
-
-        # 2: observed + live TCP subscriber + concurrent clients
-        live_path = os.path.join(tmp, "observed.json")
-        r = child([*conc_args, *obs_args, "--subscribe", "--out",
-                   live_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("observed child failed")
-        live = load(live_path)
-        sub = live["subscriber"]
-        live_ok = (trajectories_equal(base, live)
-                   and live["obs"]["snapshots"] >= 2
-                   and sub["snapshots"] >= 2 and sub["stamped_ok"]
-                   and not sub["errors"])
-        report["observed_live"] = {
-            "trajectory_equal": trajectories_equal(base, live),
-            "hub_snapshots": live["obs"]["snapshots"],
-            "subscriber": sub, "ok": live_ok}
-        ok = ok and live_ok
-
-        # 3: observed under an injected fault schedule
-        chaos_path = os.path.join(tmp, "observed_chaos.json")
-        r = child([*conc_args, *obs_args, "--chaos", "drop_dup", "--out",
-                   chaos_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("observed chaos child failed")
-        cdoc = load(chaos_path)
-        ch = cdoc["chaos"]
-        injected = (ch["drops_request"] + ch["drops_reply"]
-                    + ch["duplicates"] + ch["delays"] + ch["resets"]
-                    + ch["torn_writes"])
-        chaos_ok = trajectories_equal(base, cdoc) and injected > 0
-        report["observed_chaos"] = {
-            "trajectory_equal": trajectories_equal(base, cdoc),
-            "faults_injected": injected, "ok": chaos_ok}
-        ok = ok and chaos_ok
-
-        # 4: SIGKILL mid-stream, restore with obs re-attached
-        ckpt = os.path.join(tmp, "ckpt_obs")
-        kill_args = [*obs_args, "--subscribe", "--ckpt-dir", ckpt,
-                     "--snapshot-every", "150", "--throttle-s", "0.002"]
-        proc = subprocess.Popen(
-            [sys.executable, "-m", "repro.server.sim", *spec_args,
-             *kill_args],
-            env=child_env, stdout=subprocess.PIPE, stderr=subprocess.PIPE)
-        log_path = os.path.join(ckpt, "replay.jsonl")
-        deadline = time.time() + 300
-        killed_mid_run = False
-        kill_after = max(150, int(0.4 * base["pool"]["messages"]))
-        while time.time() < deadline:
-            if proc.poll() is not None:
-                break
-            has_snap = os.path.isdir(ckpt) and any(
-                f.startswith("snapshot_") for f in os.listdir(ckpt))
-            log_lines = 0
-            if os.path.exists(log_path):
-                with open(log_path, "rb") as f:
-                    log_lines = f.read().count(b"\n")
-            if has_snap and log_lines >= kill_after:
-                proc.send_signal(signal.SIGKILL)
-                proc.wait(timeout=30)
-                killed_mid_run = True
-                break
-            time.sleep(0.02)
-        if not killed_mid_run:
-            proc.kill()
-            report["kill_restore"] = {"killed_mid_run": False, "ok": False}
-            ok = False
-        else:
-            out_path = os.path.join(tmp, "resume_obs.json")
-            r = child([*kill_args, "--resume", "--out", out_path])
-            if r.returncode != 0:
-                print(r.stdout + r.stderr)
-                report["kill_restore"] = {"killed_mid_run": True,
-                                          "ok": False,
-                                          "error": "resume child failed"}
-                ok = False
-            else:
-                res = load(out_path)
-                k_ok = (trajectories_equal(base, res)
-                        and not res["recovered_done"])
-                report["kill_restore"] = {
-                    "killed_mid_run": True,
-                    "recovered_done": res["recovered_done"],
-                    "replayed": res["replayed"],
-                    "hub_snapshots": res["obs"]["snapshots"],
-                    "trajectory_equal": trajectories_equal(base, res),
-                    "ok": k_ok}
-                ok = ok and k_ok
-
-        # 5: live defense records its schedule; a replay reproduces it
-        sched_path = os.path.join(tmp, "schedule.json")
-        def_path = os.path.join(tmp, "defended.json")
-        r = child([*obs_args, "--defense", "--defense-out", sched_path,
-                   "--out", def_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("defense child failed")
-        defended = load(def_path)
-        d = defended["defense"]
-        shrunk = (defended["registry"]["reliable_set"]
-                  < base["registry"]["reliable_set"])
-        rep_path = os.path.join(tmp, "replayed.json")
-        r = child([*obs_args, "--defense-replay", sched_path, "--out",
-                   rep_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("defense replay child failed")
-        replayed = load(rep_path)
-        defense_ok = (d["quarantined_now"] > 0 and shrunk
-                      and trajectories_equal(defended, replayed)
-                      and replayed["defense"]["mode"] == "replay"
-                      and replayed["defense"]["quarantined_now"]
-                      == d["quarantined_now"])
-        report["defense"] = {
-            "events": d["events"], "by_action": d["by_action"],
-            "quarantined_now": d["quarantined_now"],
-            "reliable_set_defended": defended["registry"]["reliable_set"],
-            "reliable_set_undefended": base["registry"]["reliable_set"],
-            "reliable_set_shrunk": shrunk,
-            "replay_trajectory_equal": trajectories_equal(defended,
-                                                          replayed),
-            "ok": defense_ok}
-        ok = ok and defense_ok
-    except Exception as e:  # noqa: BLE001 — smoke must report, not die
-        report["error"] = str(e)
-        ok = False
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    report["parity_ok"] = ok
-    path = os.path.join(out_dir, "substrate_obs_server.json")
-    with open(path, "w") as f:
-        json.dump(report, f, indent=2)
-    print(f"[{'ok' if ok else 'FAIL'}] substrate obs_server: "
-          f"live={report.get('observed_live', {}).get('ok')} "
-          f"chaos={report.get('observed_chaos', {}).get('ok')} "
-          f"kill={report.get('kill_restore', {}).get('ok')} "
-          f"defense={report.get('defense', {}).get('ok')} "
-          f"-> {path}")
-    return ok
-
-
-def run_postmortem_smoke(out_dir: str, n_hosts: int = 48, m: int = 12,
-                         iterations: int = 3, n_stars: int = 200,
-                         n_clients: int = 8) -> bool:
-    """Post-mortem-plane smoke (``--substrate postmortem``, DESIGN.md
-    §14).  Same silenced smoke world as the obs_server smoke; four legs:
-
-      1. the UNOBSERVED serial loopback baseline;
-      2. retention byte-compatibility: two checkpointed runs — retention
-         plus full tracing ON vs OFF — must write byte-identical replay
-         logs (the §14 recovery-compatibility argument) and both match
-         the baseline trajectory;
-      3. flight recorder under fire: chaotic concurrent TCP with
-         retention + tracing, SIGKILLed mid-run.  The CLI
-         (``repro.launch.obs_postmortem``) must reconstruct the dead
-         server's timeline from the surviving store (epoch 1: snapshots,
-         spans, phase transitions, replay-log extent) WITHOUT writing an
-         epoch marker; the restored run then appends under epoch 2 and
-         its trajectory is bit-identical to the baseline;
-      4. windowed stall defense: ``--stall-window`` kills the stalled
-         search through the director seam, the verdict is recorded in
-         the anomaly schedule, and a REPLAY run applies the recorded
-         kill at the recorded seq — bit-identical to the defended run
-         (which, having been truncated by the kill, differs from the
-         undefended baseline).
-
-    Writes artifacts/dryrun/substrate_postmortem.json; returns pass/fail.
-    """
-    import shutil
-    import signal
-    import subprocess
-    import sys
-    import tempfile
-
-    child_env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    child_env["JAX_PLATFORMS"] = "cpu"
-    src_dir = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                           ".."))
-    child_env["PYTHONPATH"] = src_dir + (
-        ":" + child_env["PYTHONPATH"] if child_env.get("PYTHONPATH") else "")
-    spec_args = ["--n-hosts", str(n_hosts), "--m", str(m),
-                 "--iterations", str(iterations), "--n-stars", str(n_stars),
-                 "--silence-at", "150", "--silence-frac", "0.25"]
-    retain_args = ["--retain", "--trace-rate", "1.0",
-                   "--stats-interval", "10"]
-    conc_args = ["--transport", "tcp", "--concurrent", str(n_clients)]
-
-    def child(extra, timeout=600, module="repro.server.sim"):
-        cmd = [sys.executable, "-m", module] + extra
-        return subprocess.run(cmd, env=child_env, timeout=timeout,
-                              capture_output=True, text=True)
-
-    def load(path):
-        with open(path) as f:
-            return json.load(f)
-
-    def trajectories_equal(a, b):
-        return (a["history"] == b["history"]
-                and a["iteration"] == b["iteration"]
-                and a["best_fitness"] == b["best_fitness"]
-                and a["engine_stats"] == b["engine_stats"])
-
-    tmp = tempfile.mkdtemp(prefix="postmortem_smoke_")
-    report = {"n_hosts": n_hosts, "m": m, "iterations": iterations,
-              "n_clients": n_clients, "silence_at": 150.0,
-              "silence_frac": 0.25}
-    ok = True
-    try:
-        # 1: the unobserved baseline
-        base_path = os.path.join(tmp, "base.json")
-        r = child([*spec_args, "--out", base_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("unobserved baseline child failed")
-        base = load(base_path)
-
-        # 2: replay logs byte-compatible with retention on/off
-        ck_off = os.path.join(tmp, "ck_off")
-        ck_on = os.path.join(tmp, "ck_on")
-        off_path = os.path.join(tmp, "retain_off.json")
-        on_path = os.path.join(tmp, "retain_on.json")
-        r = child([*spec_args, "--ckpt-dir", ck_off, "--snapshot-every",
-                   "150", "--out", off_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("retention-off child failed")
-        r = child([*spec_args, "--ckpt-dir", ck_on, "--snapshot-every",
-                   "150", *retain_args, "--out", on_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("retention-on child failed")
-        with open(os.path.join(ck_off, "replay.jsonl"), "rb") as f:
-            log_off = f.read()
-        with open(os.path.join(ck_on, "replay.jsonl"), "rb") as f:
-            log_on = f.read()
-        on_doc = load(on_path)
-        bytes_ok = (log_off == log_on and len(log_off) > 0
-                    and trajectories_equal(base, load(off_path))
-                    and trajectories_equal(base, on_doc)
-                    and on_doc["retention"]["snapshots_stored"] > 0
-                    and on_doc["retention"]["spans_stored"] > 0)
-        report["replay_log_byte_compat"] = {
-            "bytes": len(log_off), "identical": log_off == log_on,
-            "retention": on_doc["retention"], "trace": on_doc["trace"],
-            "ok": bytes_ok}
-        ok = ok and bytes_ok
-
-        # 3: chaotic TCP + retention + tracing, SIGKILL, reconstruct,
-        # restore under a new epoch
-        ckpt = os.path.join(tmp, "ckpt_pm")
-        kill_args = [*spec_args, *conc_args, "--chaos", "drop_dup",
-                     *retain_args, "--ckpt-dir", ckpt,
-                     "--snapshot-every", "150", "--throttle-s", "0.002"]
-        proc = subprocess.Popen(
-            [sys.executable, "-m", "repro.server.sim", *kill_args],
-            env=child_env, stdout=subprocess.PIPE, stderr=subprocess.PIPE)
-        log_path = os.path.join(ckpt, "replay.jsonl")
-        deadline = time.time() + 300
-        killed_mid_run = False
-        kill_after = max(150, int(0.4 * base["pool"]["messages"]))
-        while time.time() < deadline:
-            if proc.poll() is not None:
-                break
-            has_snap = os.path.isdir(ckpt) and any(
-                f.startswith("snapshot_") for f in os.listdir(ckpt))
-            log_lines = 0
-            if os.path.exists(log_path):
-                with open(log_path, "rb") as f:
-                    log_lines = f.read().count(b"\n")
-            if has_snap and log_lines >= kill_after:
-                proc.send_signal(signal.SIGKILL)
-                proc.wait(timeout=30)
-                killed_mid_run = True
-                break
-            time.sleep(0.02)
-        if not killed_mid_run:
-            proc.kill()
-            report["flight_recorder"] = {"killed_mid_run": False,
-                                         "ok": False}
-            ok = False
-        else:
-            # the CLI reconstructs the DEAD run's timeline, read-only
-            pm_dead = os.path.join(tmp, "pm_dead.json")
-            r = child(["--ckpt-dir", ckpt, "--json", "--out", pm_dead],
-                      module="repro.launch.obs_postmortem")
-            if r.returncode != 0:
-                print(r.stdout + r.stderr)
-                raise RuntimeError("postmortem CLI failed on dead store")
-            dead = load(pm_dead)
-            dead_ok = (dead["store"]["epochs"] == [1]
-                       and dead["store"]["records"] > 0
-                       and dead["spans"] > 0
-                       and len(dead["phases"]) > 0
-                       and dead["replay_log"]["records"] >= kill_after)
-            out_path = os.path.join(tmp, "resume_pm.json")
-            r = child([*kill_args, "--resume", "--out", out_path])
-            if r.returncode != 0:
-                print(r.stdout + r.stderr)
-                report["flight_recorder"] = {"killed_mid_run": True,
-                                             "dead_report_ok": dead_ok,
-                                             "ok": False,
-                                             "error": "resume failed"}
-                ok = False
-            else:
-                res = load(out_path)
-                pm_post = os.path.join(tmp, "pm_post.json")
-                r = child(["--ckpt-dir", ckpt, "--json", "--out", pm_post],
-                          module="repro.launch.obs_postmortem")
-                post = load(pm_post) if r.returncode == 0 else {}
-                # the read-only CLI added no epoch; the restored server
-                # appended under epoch 2
-                epochs_ok = post.get("store", {}).get("epochs") == [1, 2]
-                k_ok = (dead_ok and epochs_ok
-                        and trajectories_equal(base, res)
-                        and not res["recovered_done"])
-                report["flight_recorder"] = {
-                    "killed_mid_run": True,
-                    "dead_epochs": dead["store"]["epochs"],
-                    "dead_snapshots": dead["store"]["by_type"].get("snap"),
-                    "dead_spans": dead["spans"],
-                    "dead_phase_transitions": len(dead["phases"]),
-                    "replay_log_records": dead["replay_log"]["records"],
-                    "post_restore_epochs":
-                        post.get("store", {}).get("epochs"),
-                    "replayed": res["replayed"],
-                    "trajectory_equal": trajectories_equal(base, res),
-                    "ok": k_ok}
-                ok = ok and k_ok
-
-        # 4: stall-window kill recorded live, replayed bit-identically
-        sched_path = os.path.join(tmp, "stall_schedule.json")
-        def_path = os.path.join(tmp, "stalled.json")
-        stall_args = ["--stats-interval", "10", "--stall-window", "3"]
-        r = child([*spec_args, *stall_args, "--defense-out", sched_path,
-                   "--out", def_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("stall-defense child failed")
-        defended = load(def_path)
-        d = defended["defense"]
-        rep_path = os.path.join(tmp, "stall_replayed.json")
-        r = child([*spec_args, "--stats-interval", "10",
-                   "--defense-replay", sched_path, "--out", rep_path])
-        if r.returncode != 0:
-            print(r.stdout + r.stderr)
-            raise RuntimeError("stall-replay child failed")
-        replayed = load(rep_path)
-        stall_ok = (d["searches_killed"] == [0]
-                    and d["by_action"].get("kill_search", 0) >= 1
-                    and trajectories_equal(defended, replayed)
-                    and replayed["defense"]["searches_killed"] == [0]
-                    and replayed["defense"]["mode"] == "replay"
-                    and defended["iteration"] < base["iteration"])
-        report["stall_kill"] = {
-            "searches_killed": d["searches_killed"],
-            "by_action": d["by_action"],
-            "defended_iteration": defended["iteration"],
-            "baseline_iteration": base["iteration"],
-            "replay_trajectory_equal": trajectories_equal(defended,
-                                                          replayed),
-            "ok": stall_ok}
-        ok = ok and stall_ok
-    except Exception as e:  # noqa: BLE001 — smoke must report, not die
-        report["error"] = str(e)
-        ok = False
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-    report["parity_ok"] = ok
-    path = os.path.join(out_dir, "substrate_postmortem.json")
-    with open(path, "w") as f:
-        json.dump(report, f, indent=2)
-    print(f"[{'ok' if ok else 'FAIL'}] substrate postmortem: "
-          f"bytes={report.get('replay_log_byte_compat', {}).get('ok')} "
-          f"recorder={report.get('flight_recorder', {}).get('ok')} "
-          f"stall={report.get('stall_kill', {}).get('ok')} "
-          f"-> {path}")
-    return ok
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
@@ -1551,27 +206,11 @@ def main():
     ap.add_argument("--quant-cache", action="store_true",
                     help="int8 KV/latent cache (perf variant)")
     ap.add_argument("--suffix", default="", help="artifact filename suffix")
-    # choices come from the ONE substrate registry (launch/substrates.py):
-    # an unknown substrate fails at parse time instead of falling through
-    # to the model-cell path
-    ap.add_argument("--substrate", default=None,
-                    choices=sorted(SUBSTRATES),
-                    help="run the substrate smoke instead of model cells")
-    ap.add_argument("--list-substrates", action="store_true",
-                    help="print the registered substrate smokes and exit")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
-    if args.list_substrates:
-        print(list_substrates())
-        raise SystemExit(0)
-
     out_dir = args.out or os.path.abspath(ARTIFACTS)
     os.makedirs(out_dir, exist_ok=True)
-
-    if args.substrate is not None:
-        runner = SUBSTRATES[args.substrate].resolve()
-        raise SystemExit(0 if runner(out_dir) else 1)
     meshes = {"pod": [False], "multipod": [True], "both": [False, True]}[args.mesh]
 
     archs = ARCH_NAMES if (args.all or args.arch is None) else [args.arch]

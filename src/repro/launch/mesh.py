@@ -20,8 +20,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     if len(devices) < n:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices but only {len(devices)} present; "
-            "launch via repro.launch.dryrun (it sets "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=512)")
+            "on the CPU, set "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
+            "jax is imported")
     return jax.make_mesh(shape, axes, devices=devices[:n])
 
 

@@ -6,7 +6,7 @@ stamped snapshot — fleet states, reliable set, service pressure, per-
 search phase/iteration/best, message rate with a sparkline.  Because the
 stream is served by the same unstamped/unlogged path as ``status``,
 watching a run CANNOT perturb it: the committed iterates are bit-identical
-with or without a dashboard attached (the obs_server dryrun smoke gates
+with or without a dashboard attached (``tests/test_obs.py`` pins
 exactly this).
 
     # against a live server

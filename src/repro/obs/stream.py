@@ -53,7 +53,7 @@ class StatsSubscriber:
 
 class BackgroundSubscriber:
     """A daemon thread polling ``subscribe_stats`` while a run is live —
-    the dryrun smoke's live TCP subscriber and the dashboard's feed.
+    the sim CLI's ``--subscribe`` poller and the dashboard's feed.
 
     ``connect`` is called on the thread (so a TCP connect cannot block
     the caller); snapshots are appended under a lock and optionally

@@ -110,8 +110,8 @@ class FaultPlan:
 
 
 #: the named plans the parity gates cycle through — three distinct fault
-#: mixes (loss+duplication, reordering delay, resets+torn writes) plus the
-#: ledger's degraded-mode operating point (10% drop / 5% duplication)
+#: mixes (loss+duplication, reordering delay, resets+torn writes) plus a
+#: degraded-mode operating point (10% drop / 5% duplication)
 PRESETS: Dict[str, FaultPlan] = {
     "drop_dup": FaultPlan(name="drop_dup", seed=101, drop_request=0.08,
                           drop_reply=0.06, duplicate=0.10),

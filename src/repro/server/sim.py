@@ -16,7 +16,8 @@ server was killed and restored in between.  After a restore,
 from the lease tables (outstanding AND lapsed) plus each idle host's
 ``next_contact_at``: outstanding work is re-leased to exactly the hosts
 that held it, so the restored run replays the uninterrupted future —
-bit-identical committed iterates, the contract the dryrun smoke gates.
+bit-identical committed iterates, the contract
+``tests/test_sigkill_restore.py`` holds real killed processes to.
 
 Event ordering is canonical — ``(time, kind-priority, host)``, completions
 before requests — NOT insertion order, so a rebuilt queue sorts exactly
@@ -44,7 +45,7 @@ fault-free baseline.
 ``WorkServer``, attach the checkpoint manager, start a transport (with
 ``concurrent``/``chaos``, a sequenced intake and/or fault-plan wrapper),
 run the pool to completion.  ``python -m repro.server.sim`` runs a seeded
-single-search smoke — the dryrun kill/restore harness launches it as a
+single-search smoke — ``tests/test_sigkill_restore.py`` launches it as a
 subprocess, SIGKILLs it mid-search, and relaunches with ``--resume``.
 """
 from __future__ import annotations
@@ -152,7 +153,6 @@ class SimClientPool:
         # message carries (serial traffic too, so the wire is uniform);
         # after a resume they continue from the server's last applied cs
         self._cs: Dict[int, int] = {}
-        self.request_wall: List[float] = []   # request_work round-trip walls
 
     # -- crash-restore rebuild ----------------------------------------------
 
@@ -245,18 +245,13 @@ class SimClientPool:
             raise SimulatedCrash(
                 f"simulated crash after {self.stats.messages} messages")
         self.stats.messages += 1
-        t0 = time.perf_counter()
         if spans.enabled():
             # one span a message, the round trip through the connection
             # (codec both ways, WorkServer.handle): per-message wrappers
             # deeper down would cost the untraced hot loop a frame each
             with spans.span("intake.transport"):
-                rep = conn.call(msg)
-        else:
-            rep = conn.call(msg)
-        if msg.get("kind") == "request_work":
-            self.request_wall.append(time.perf_counter() - t0)
-        return rep
+                return conn.call(msg)
+        return conn.call(msg)
 
     @spans.spanned("fleet.run")
     def run(self, conn) -> PoolStats:
@@ -490,11 +485,7 @@ class ConcurrentClientPool(SimClientPool):
                     try:
                         rep = None
                         for m in msgs:
-                            t0 = time.perf_counter()
                             rep = conn.call(m)
-                            if m.get("kind") == "request_work":
-                                self.request_wall.append(
-                                    time.perf_counter() - t0)
                         results.put((ev, rep, None, partial))
                     except BaseException as e:  # noqa: BLE001 — surfaced
                         results.put((ev, None, e, partial))
@@ -562,7 +553,6 @@ class ServerRunResult:
     cache: Optional[dict] = None      # eval-cache counters, when enabled
     chaos: Optional[dict] = None      # injected-fault counters + plan doc
     intake: Optional[dict] = None     # sequenced-intake counters
-    request_p99_ms: Optional[float] = None  # p99 request_work round-trip
     obs: Optional[dict] = None        # metrics-hub summary, when observed
     subscriber: Optional[dict] = None  # live stats-poller summary
     defense: Optional[dict] = None    # anomaly summary + recorded schedule
@@ -836,10 +826,6 @@ class ServerSubstrate:
                 self.cache.store.flush()
             if store is not None and mgr is None:
                 store.close()
-        p99 = None
-        if pool.request_wall:
-            p99 = float(np.percentile(np.asarray(pool.request_wall),
-                                      99.0) * 1000.0)
         obs_doc = None
         if hub is not None:
             latest = hub.latest()
@@ -862,7 +848,7 @@ class ServerSubstrate:
                                    "next_seq": intake.next_seq,
                                    "parked": intake.parked,
                                    "out_of_band": intake.out_of_band},
-                               request_p99_ms=p99, obs=obs_doc,
+                               obs=obs_doc,
                                subscriber=None if subscriber is None
                                else subscriber.summary(),
                                defense=defense_doc,
@@ -871,7 +857,7 @@ class ServerSubstrate:
                                else tracer.summary())
 
 
-# -- the seeded smoke problem + CLI (dryrun's kill/restore subprocess) --------
+# -- the seeded smoke problem + CLI (the kill/restore subprocess) -------------
 
 def smoke_problem(n_stars: int = 400, n_hosts: int = 192, m: int = 24,
                   iterations: int = 4, engine_seed: int = 7,
@@ -879,7 +865,7 @@ def smoke_problem(n_stars: int = 400, n_hosts: int = 192, m: int = 24,
                   malicious: float = 0.02, quorum: int = 2):
     """The fixed seeded workload every kill/restore gate compares across
     runs: (spec, fleet, f_batch).  Parameters ARE the identity — the
-    dryrun harness passes the same values to every subprocess."""
+    kill/restore tests pass the same values to every subprocess."""
     from repro.core.anm import AnmConfig
     from repro.data import sdss
 
@@ -950,7 +936,6 @@ def result_doc(res: ServerRunResult) -> dict:
         "cache": res.cache,
         "chaos": res.chaos,
         "intake": res.intake,
-        "request_p99_ms": res.request_p99_ms,
         "obs": res.obs,
         "subscriber": res.subscriber,
         "defense": res.defense,
@@ -965,7 +950,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     import os
 
     ap = argparse.ArgumentParser(
-        description="seeded single-search server smoke (the dryrun "
+        description="seeded single-search server smoke (the "
                     "kill/restore subprocess)")
     ap.add_argument("--transport", default="loopback",
                     choices=["loopback", "tcp"])
@@ -1123,6 +1108,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             json.dump(res.defense["schedule"], f, indent=2)
     doc["transport"] = args.transport
     doc["backend"] = args.backend
+    doc["data_shards"] = getattr(backend, "n_shards", 1)
     doc["problem"] = args.problem
     doc["concurrent"] = args.concurrent
     if args.problem == "lm":

@@ -11,7 +11,7 @@ both present the same two-sided API::
 **Loopback** round-trips every message through real ``encode``/``decode``
 bytes (so serialization bugs cannot hide behind in-process object passing)
 but stays single-threaded and allocation-cheap — the deterministic
-transport the tests, dryrun smoke and benchmarks drive.
+transport the tests and benchmarks drive.
 
 **TCP** runs an asyncio server on a background thread; each connection is
 served frame-by-frame in arrival order.  With the default in-loop handler,

@@ -1,11 +1,19 @@
 """LM-loss evaluation backend: the model stack as the engine's fitness.
 
-Pins the DESIGN.md §11 contracts on the single real CPU device (the
-512-device pod variant is exercised by ``--substrate lm_subspace``):
-shared subspace machinery, zero compiles after warm, bucket-width
-invariance, sync == pipelined trajectories, and unmodified composition
-with ``CachingSubmitter`` and the work server.
+Pins the DESIGN.md §11 contracts on the single real CPU device: shared
+subspace machinery, zero compiles after warm, bucket-width invariance,
+sync == pipelined trajectories, and unmodified composition with
+``CachingSubmitter`` and the work server.  The pod variant, with θ0 and
+the basis stored sharded over ``model``, runs in a child process on the
+production 16x16 mesh of 512 forced host devices (and on a (2, 2) mesh
+of chips in ``chip_smoke.py --chips 4``).
 """
+import json
+import os
+import subprocess
+import sys
+import time
+
 import numpy as np
 import pytest
 
@@ -170,7 +178,7 @@ class TestGridTrajectories:
     @pytest.fixture(scope="class")
     def problem(self):
         from repro.server.sim import lm_problem
-        # the canonical problem builder the dryrun smoke and example use,
+        # the canonical problem builder the example uses,
         # scaled down: 16 hosts, one iteration
         spec, fleet, wl = lm_problem(arch="rwkv6-7b", k=K, n_hosts=16,
                                      m=6, iterations=1)
@@ -214,3 +222,44 @@ class TestGridTrajectories:
                                              ckpt_dir=ckpt).run(resume=True))
         assert res["history"] == base["history"]
         assert res["engine_stats"] == base["engine_stats"]
+
+
+@pytest.mark.server
+def test_production_mesh_child_commits_the_in_process_trajectory(
+        child_env, tmp_path):
+    """``python -m repro.server.sim --problem lm`` served through the pod
+    backend on the production (data=16, model=16) mesh commits exactly
+    what an in-process child commits, at the old dryrun smoke's size.
+    The two children run side by side, each under a deadline."""
+    size = ["--problem", "lm", "--n-hosts", "48", "--m", "12",
+            "--iterations", "2", "--k", "6"]
+    forced = dict(child_env,
+                  XLA_FLAGS="--xla_force_host_platform_device_count=512")
+    runs = {"in_process": (child_env, []),
+            "pod_mesh": (forced, ["--backend", "pod_mesh"])}
+    procs = {name: subprocess.Popen(
+                 [sys.executable, "-m", "repro.server.sim", *size, *extra,
+                  "--out", str(tmp_path / f"{name}.json")],
+                 env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+                 text=True)
+             for name, (env, extra) in runs.items()}
+    deadline = time.monotonic() + 120
+    try:
+        for proc in procs.values():
+            out, _ = proc.communicate(
+                timeout=max(1.0, deadline - time.monotonic()))
+            assert proc.returncode == 0, out
+    finally:
+        for proc in procs.values():
+            if proc.poll() is None:
+                proc.kill()
+                proc.wait(timeout=30)
+    docs = {}
+    for name in runs:
+        with open(os.path.join(tmp_path, f"{name}.json")) as f:
+            docs[name] = json.load(f)
+    base, pod = docs["in_process"], docs["pod_mesh"]
+    assert (base["data_shards"], pod["data_shards"]) == (1, 16)
+    assert base["iteration"] == 2
+    for key in ("history", "iteration", "best_fitness", "engine_stats"):
+        assert pod[key] == base[key], key

@@ -52,7 +52,7 @@ def _quad_fitness(n=8, seed=3):
 
 def _solo_run(spec: SearchSpec, backend, *, pipelined=True):
     """The parity baseline — `SearchSpec.solo_run` is the ONE shared
-    construction (tests, dryrun smoke, benchmark, example all use it)."""
+    construction (tests, benchmark, example all use it)."""
     return spec.solo_run(backend, pipelined=pipelined)
 
 
@@ -95,7 +95,7 @@ def test_coalesced_searches_match_solo_runs_bit_identically():
 
 def test_multi_search_parity_on_pod_backend():
     """The same contract through the shard_map backend (degenerate mesh on
-    a single-device CPU — the real 16x16 runs in the dryrun smoke)."""
+    a single-device CPU)."""
     f_batch, _ = _quad_fitness()
     backend = PodMeshEvalBackend(f_batch)
     res, _ = _portfolio(backend, 3, n_hosts=384, m=24, iters=2)

@@ -513,13 +513,3 @@ def test_server_status_message_is_read_only(backend):
     assert rep["searches"][0]["phase"] == "bootstrap"
     after = json.dumps(to_jsonable(srv.state_dict()), sort_keys=True)
     assert before == after
-
-
-def test_substrate_registry_names():
-    """The one registry dict the dryrun CLI and scalability derive from."""
-    from repro.launch.substrates import SUBSTRATES, list_substrates
-    assert {"pod_mesh", "multi_search", "server"} <= set(SUBSTRATES)
-    for s in SUBSTRATES.values():
-        mod, fn = s.runner.split(":")
-        assert mod and fn
-    assert "server" in list_substrates()

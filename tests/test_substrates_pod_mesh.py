@@ -1,12 +1,14 @@
-"""Pod-mesh evaluation backend: bucket framing, shard_map parity, and the
-dryrun forced-host-device smoke (DESIGN.md §6).
+"""Pod-mesh evaluation backend: bucket framing and shard_map parity
+(DESIGN.md §6).  The production 16x16 mesh runs in a child process that
+sees 512 forced host devices, here for the batched grid and the
+coalesced orchestrator, and in ``tests/test_sigkill_restore.py`` for the
+work server.
 
 The contract under test: WHERE a workunit block is evaluated is invisible
 to the engine — the pod-mesh backend must commit bit-identical iterates to
 the in-process backend at the same engine seed and grid config.
 """
 import json
-import os
 import subprocess
 import sys
 
@@ -124,30 +126,73 @@ def test_pod_and_in_process_backends_commit_identical_iterates():
     assert s_in.completed == s_pod.completed
 
 
-# -- the real partitioning, under dryrun's forced 512-device mesh --------------
+# -- the real partitioning, on 512 forced host devices -------------------------
 
-@pytest.mark.slow
-def test_dryrun_pod_mesh_smoke_parity(tmp_path):
-    """Run the `--substrate pod_mesh` dryrun in a subprocess (it forces
-    XLA_FLAGS=--xla_force_host_platform_device_count=512 before importing
-    jax) and require the bit-identical parity report on the production
-    16x16 mesh."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(repo, "src"),
-               JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.launch.dryrun",
-         "--substrate", "pod_mesh", "--out", str(tmp_path)],
-        capture_output=True, text=True, timeout=900, env=env, cwd=repo)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    report = json.loads((tmp_path / "substrate_pod_mesh.json").read_text())
-    assert report["parity_ok"] is True
-    assert report["pipelined_parity_ok"] is True
-    assert report["pod_parity_ok"] is True
-    assert report["centers_equal"] is True
-    assert report["fitness_equal"] is True
-    assert report["data_shards"] == 16
-    assert report["iterations"]["in_process"] == \
-        report["iterations"]["pod_mesh"] == \
-        report["iterations"]["in_process_pipelined"]
+# The batched grid sync and pipelined through both backends, then a
+# coalesced two-search portfolio through both, at the old dryrun smoke's
+# size; prints what the test compares.
+_PRODUCTION_MESH_CHILD = """
+import json
+import numpy as np
+from repro.core.anm import AnmConfig
+from repro.core.engine import AnmEngine, identical_trajectories
+from repro.core.grid import GridConfig
+from repro.core.orchestrator import (FleetScheduler, SearchDirector,
+                                     multi_start_specs)
+from repro.core.substrates.batched_grid import BatchedVolunteerGrid
+from repro.core.substrates.eval_backend import InProcessEvalBackend
+from repro.core.substrates.pod_mesh import PodMeshEvalBackend
+from repro.data import sdss
+
+stripe = sdss.make_stripe("podmesh", n_stars=500, seed=17)
+f_batch, _ = sdss.make_fitness(stripe)
+x0 = np.clip(stripe.truth + np.random.default_rng(3).normal(0, 0.2, 8)
+             .astype(np.float32), sdss.LO, sdss.HI)
+anm = AnmConfig(m_regression=32, m_line_search=32, max_iterations=2)
+fleet = GridConfig(n_hosts=512, failure_prob=0.05, malicious_prob=0.01,
+                   seed=9)
+in_process, pod = InProcessEvalBackend(f_batch), PodMeshEvalBackend(f_batch)
+
+def grid(backend, pipelined):
+    engine = AnmEngine(x0, sdss.LO, sdss.HI, sdss.DEFAULT_STEP, anm, seed=7)
+    BatchedVolunteerGrid(f_batch, fleet, backend=backend,
+                         pipelined=pipelined).run(engine)
+    return engine
+
+def portfolio(backend):
+    sched = FleetScheduler(backend, fleet)
+    specs = multi_start_specs(sched, x0, sdss.LO, sdss.HI, sdss.DEFAULT_STEP,
+                              anm, 2, seed=7, jitter=0.3)
+    return SearchDirector(sched, specs).run().outcomes
+
+def same(a, b):
+    return identical_trajectories(a, b) and a.stats == b.stats
+
+ref = grid(in_process, False)
+runs = [grid(in_process, True), grid(pod, False), grid(pod, True)]
+coalesced, in_process_coalesced = portfolio(pod), portfolio(in_process)
+print(json.dumps({
+    "n_shards": pod.n_shards, "iterations": ref.iteration,
+    "grid": [same(ref, e) for e in runs],
+    "solo": [same(o.engine, o.spec.solo_run(pod)) for o in coalesced],
+    "across_backends": [same(a.engine, b.engine) for a, b in
+                        zip(coalesced, in_process_coalesced)]}))
+"""
+
+
+def test_production_mesh_grid_and_portfolio_match_in_process(child_env):
+    """On the production (data=16, model=16) mesh the batched grid, sync
+    and pipelined, commits the in-process sync trajectory, and each
+    search of a coalesced portfolio commits its solo run and its
+    in-process twin."""
+    env = dict(child_env,
+               XLA_FLAGS="--xla_force_host_platform_device_count=512")
+    r = subprocess.run([sys.executable, "-c", _PRODUCTION_MESH_CHILD],
+                       env=env, capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stdout + r.stderr
+    got = json.loads(r.stdout.strip().splitlines()[-1])
+    assert got["n_shards"] == 16
+    assert got["iterations"] == 2
+    assert got["grid"] == [True] * 3
+    assert got["solo"] == [True] * 2
+    assert got["across_backends"] == [True] * 2
