@@ -369,9 +369,7 @@ def compiled_text(backend, n_points: int) -> str:
     kp = bucket_size(n_points, backend.min_bucket)
     n = backend.workload.k
     return backend._eval.lower(
-        jax.ShapeDtypeStruct((kp, n), np.float32),
-        jax.ShapeDtypeStruct((kp,), np.float32),
-        np.int32(n_points)).compile().as_text()
+        jax.ShapeDtypeStruct((kp, n + 1), np.float32)).compile().as_text()
 
 
 def phase_lm(arch: str = "rwkv6-7b", k: int = 8, n_points: int = 16,

@@ -23,12 +23,19 @@ The synchronous form ``backend(pts, mal_u)`` remains
 ``collect(submit(...))``, so non-pipelined callers are unchanged.
 
 Framing.  Every block of ``k`` points is written into a PERSISTENT
-per-bucket staging buffer padded up to a power-of-two bucket (pad lanes
-repeat the last real point), so the steady state pays one buffer fill per
-tick — no per-tick ``np.concatenate``/``np.repeat`` allocations, and on
-CPU the XLA client aliases the numpy buffer outright (zero copy): the
-staging buffers ARE the device buffers.  That aliasing is exactly why
-they form a RING (``STAGING_RING`` deep) per bucket size: a submitted
+per-bucket staging buffer padded up to a power-of-two bucket ``kp``: one
+``(kp, n + 1)`` float32 array, the points in columns ``0..n-1`` (pad
+lanes repeat the last real point) and each lane's malicious draw in
+column ``n`` (NaN for an honest lane, ``PAD_MARK`` for a pad lane).  A
+dispatch thus hands the device ONE host array and pays one
+host-to-device transfer: on the chip, the points, the draws and a lane
+count as three arguments cost ~578 us a dispatch against ~235 us for
+the one packed array (PERF.md §5).  The steady state pays one buffer
+fill per tick — no per-tick ``np.concatenate``/``np.repeat``
+allocations, and on CPU the XLA client aliases the numpy buffer
+outright (zero copy): the staging buffers ARE the device buffers.  That
+aliasing is exactly why they form a RING (``STAGING_RING`` deep) per
+bucket size: a submitted
 bucket may still be reading its buffer while the host stages the next
 tick, so consecutive submits of one shape rotate through distinct
 buffers, classic double-buffering — callers may keep at most
@@ -46,10 +53,10 @@ performs ZERO compiles mid-run — pinned by the ``compile_count`` probe in
 the substrate tests.
 
 Results come back FINAL (DESIGN.md §7): the jitted bucket finalization
-applies the sign-safe malicious corruption ``grid.malicious_lie`` to the
-lanes whose ``mal_u`` draw is non-NaN and masks the pad lanes to NaN
-on-device, so ``collect`` never patches values on the host after a
-blocking fetch.
+splits the staged array into points and draws, applies the sign-safe
+malicious corruption ``grid.malicious_lie`` to the lanes whose draw is
+finite and masks the ``PAD_MARK`` lanes to NaN on-device, so ``collect``
+never patches values on the host after a blocking fetch.
 
 Two backends ship with the repo:
 
@@ -80,6 +87,11 @@ DEFAULT_MIN_BUCKET = 8
 #: buckets of one shape — restaging a slot whose handle is uncollected
 #: raises (per-slot flags, so out-of-order collects are handled exactly).
 STAGING_RING = 8
+
+#: the staged draw column's mark for a pad lane: neither NaN (honest) nor
+#: a draw in [0.2, 0.8] (malicious), so the device tells the real lanes
+#: from the pad without a lane count
+PAD_MARK = np.float32(np.inf)
 
 
 def bucket_size(k: int, min_bucket: int = DEFAULT_MIN_BUCKET) -> int:
@@ -120,8 +132,9 @@ class EvalBackend:
     finalization around a subclass evaluation.
 
     Subclasses implement ``_raw_eval((kp, n) f32 block) -> (kp,) fitness``
-    — traced inside this class's jitted finalization — for ``kp`` already
-    padded to a power-of-two multiple of the backend's lane count.  This
+    — traced inside this class's jitted finalization, on the points'
+    columns of the staged array — for ``kp`` already padded to a
+    power-of-two multiple of the backend's lane count.  This
     class owns padding, the malicious-corruption lanes, and pad-lane NaN
     masking, so every backend frames and finalizes blocks identically (a
     parity requirement: the same engine seed must commit the same iterates
@@ -133,7 +146,7 @@ class EvalBackend:
             raise ValueError(
                 f"min_bucket must be a power of two >= 1, got {min_bucket}")
         self.min_bucket = min_bucket
-        self._bufs: dict = {}            # kp -> ring of ((kp, n), (kp,)) bufs
+        self._bufs: dict = {}            # kp -> ring of (kp, n + 1) bufs
         self._ring: dict = {}            # kp -> next ring slot
         self._slot_owner: dict = {}      # kp -> per-slot owning seq (or None)
         self._submit_seq = 0             # ownership tokens for ring slots
@@ -153,33 +166,35 @@ class EvalBackend:
         import jax
         import jax.numpy as jnp
 
-        def bucket_eval(pts, u, k):
+        def bucket_eval(staged):
             # this body runs at TRACE time only: one execution per bucket
             # shape, which is exactly what compile_count must count
             self.compile_count += 1
+            pts, u = staged[:, :-1], staged[:, -1]
             ys = self._raw_eval(pts)
             # malicious corruption as mask lanes: NaN u == honest lane
-            ys = jnp.where(jnp.isnan(u), ys, malicious_lie(ys, u))
-            # pad/overhang lanes come back NaN from the device — results
-            # are final on arrival, never patched on host
-            return jnp.where(jnp.arange(pts.shape[0]) < k, ys, jnp.nan)
+            ys = jnp.where(jnp.isfinite(u), malicious_lie(ys, u), ys)
+            # pad lanes (marker PAD_MARK) come back NaN from the device —
+            # results are final on arrival, never patched on host
+            return jnp.where(u == PAD_MARK, jnp.nan, ys)
 
         return jax.jit(bucket_eval)
 
     # -- framing -------------------------------------------------------------
 
     def _staging(self, kp: int, n: int):
-        """Next (points, mal_u, slot) staging triple in the bucket's ring.
+        """Next (staged (kp, n + 1) buffer, slot) in the bucket's ring.
         The rotation is what makes restaging safe under async dispatch:
         the previous slots may still be aliased by in-flight buckets —
         and a slot whose bucket is STILL uncollected refuses to restage
         (zero-copy aliasing would silently corrupt it otherwise)."""
         ring = self._bufs.get(kp)
-        if ring is None or ring[0][0].shape[1] != n:
-            ring = self._bufs[kp] = [
-                (np.zeros((kp, n), np.float32),
-                 np.full(kp, np.nan, np.float32))
-                for _ in range(STAGING_RING)]
+        if ring is None or ring[0].shape[1] != n + 1:
+            ring = self._bufs[kp] = []
+            for _ in range(STAGING_RING):
+                buf = np.zeros((kp, n + 1), np.float32)
+                buf[:, n] = np.nan
+                ring.append(buf)
             self._ring[kp] = 0
             self._slot_owner[kp] = [None] * STAGING_RING
         slot = self._ring[kp]
@@ -189,7 +204,7 @@ class EvalBackend:
                 f"{slot} of bucket shape {kp} (ring depth {STAGING_RING}); "
                 f"collect() in-flight handles before submitting more")
         self._ring[kp] = (slot + 1) % STAGING_RING
-        return ring[slot][0], ring[slot][1], slot
+        return ring[slot], slot
 
     def warm(self, n_dims: int, max_k: int) -> "EvalBackend":
         """Compile AND execute the whole bucket ladder (min_bucket up to
@@ -202,8 +217,8 @@ class EvalBackend:
         top = bucket_size(max_k, self.min_bucket)
         while True:
             if (n_dims, kp) not in self._warmed:
-                pts, u, _ = self._staging(kp, n_dims)
-                handles.append(self._eval(pts, u, np.int32(kp)))
+                buf, _ = self._staging(kp, n_dims)
+                handles.append(self._eval(buf))
                 self._warmed.add((n_dims, kp))
             if kp >= top:
                 break
@@ -230,19 +245,16 @@ class EvalBackend:
         values."""
         k, n = pts.shape
         kp = bucket_size(k, self.min_bucket)
-        buf, ubuf, slot = self._staging(kp, n)
+        buf, slot = self._staging(kp, n)
         self._submit_seq += 1
         self._slot_owner[kp][slot] = self._submit_seq
-        buf[:k] = pts
-        if mal_u is None:
-            ubuf[:k] = np.nan
-        else:
-            ubuf[:k] = mal_u
+        buf[:k, :n] = pts
+        buf[:k, n] = np.nan if mal_u is None else mal_u
         if kp != k:
-            buf[k:] = buf[k - 1]
-            ubuf[k:] = np.nan
+            buf[k:, :n] = buf[k - 1, :n]
+            buf[k:, n] = PAD_MARK
         self._warmed.add((n, kp))    # a lazy compile still warms the cell
-        ys = self._eval(buf, ubuf, np.int32(k))
+        ys = self._eval(buf)
         # the read-back is requested now, queued behind the bucket on the
         # device, so a deferred collect finds the values already on host
         ys.copy_to_host_async()

@@ -10,7 +10,7 @@ pay for a fitness evaluation exactly once.
 
 Why bit-exact-only serving is safe (the determinism argument, pinned by
 the parity gates): the backend stages every block as float32
-(``buf[:k] = pts``), and the repo-wide row-independence + width-invariance
+(``buf[:k, :n] = pts``), and the row-independence + width-invariance
 contract (DESIGN.md §8) already established that a lane's value is a pure
 function of its staged f32 bytes — independent of bucket width, bucket
 composition and collect timing.  A cache keyed on exactly those bytes

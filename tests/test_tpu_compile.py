@@ -18,7 +18,6 @@ import re
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -146,6 +145,5 @@ def test_lm_bucket_uses_wkv6_kernel(one_chip, on_chip_routes):
                                                make_lm_workload)
 
     be = LmLossEvalBackend(make_lm_workload("rwkv6-7b", k=8))
-    c = be._eval.lower(_shape(one_chip, (16, 8)), _shape(one_chip, (16,)),
-                       np.int32(16)).compile()
+    c = be._eval.lower(_shape(one_chip, (16, 9))).compile()
     assert _has_kernel(c)
