@@ -52,6 +52,18 @@ compiles that whole ladder up front (backends constructed with
 performs ZERO compiles mid-run — pinned by the ``compile_count`` probe in
 the substrate tests.
 
+The ladder's top is bounded by the chip.  A lane's temporaries grow with
+the fitness (~800 KB a lane for a 100k-star stripe on a v5e), so a
+ladder warmed up to a large fleet's ``n_hosts`` would ask for buckets no
+device holds.  Where the device reports its memory limit, ``warm``
+derives the widest power-of-two bucket that fits beside what the device
+already holds (``ladder_top``): it estimates a lane's bytes from the
+compiled bucket of ``PROBE_LANES`` and checks the compiled top bucket
+whenever that top cuts the ladder.  ``submit`` of a wider bucket raises
+``BucketTooWide`` and compiles nothing; callers that batch lazily (the
+served client pool) stop their batches at the top.  Where no limit is
+reported (the CPU) the ladder is unbounded, as before.
+
 Results come back FINAL (DESIGN.md §7): the jitted bucket finalization
 splits the staged array into points and draws, applies the sign-safe
 malicious corruption ``grid.malicious_lie`` to the lanes whose draw is
@@ -92,6 +104,47 @@ STAGING_RING = 8
 #: a draw in [0.2, 0.8] (malicious), so the device tells the real lanes
 #: from the pad without a lane count
 PAD_MARK = np.float32(np.inf)
+
+#: the bucket whose compiled memory a lane's bytes are estimated from: wide
+#: enough that a lane's temporaries show (the TPU compiler keeps a narrow
+#: bucket's per-lane work out of its temporaries altogether)
+PROBE_LANES = 1024
+
+
+class BucketTooWide(ValueError):
+    """A bucket wider than the backend's ladder top: it would not fit in
+    the device's memory, so it is refused before anything compiles."""
+
+    def __init__(self, k: int, kp: int, top: int):
+        super().__init__(
+            f"{k} lanes need a bucket of {kp}, above this backend's ladder "
+            f"top of {top} (the widest bucket the device's memory holds); "
+            f"split the block")
+        self.k, self.kp, self.top = k, kp, top
+
+
+def device_headroom() -> Optional[int]:
+    """Bytes the local devices can still give one program: the least of
+    ``bytes_limit - bytes_in_use`` over the devices that report a limit,
+    None where none does (the CPU)."""
+    import jax
+
+    free = [s["bytes_limit"] - s.get("bytes_in_use", 0)
+            for s in (d.memory_stats() or {} for d in jax.local_devices())
+            if "bytes_limit" in s]
+    return min(free) if free else None
+
+
+def ladder_top(backend, n_dims: int) -> Optional[int]:
+    """The widest bucket ``backend`` dispatches for ``n_dims``-parameter
+    points, as its warm-up derived it; None where it derived none.
+    Wrappers that keep the backend they wrap as ``.backend`` (the eval
+    cache's submitter, a recorder) are looked through."""
+    while not isinstance(backend, EvalBackend):
+        backend = getattr(backend, "backend", None)
+        if backend is None:
+            return None
+    return backend._tops.get(n_dims)
 
 
 def bucket_size(k: int, min_bucket: int = DEFAULT_MIN_BUCKET) -> int:
@@ -151,6 +204,7 @@ class EvalBackend:
         self._slot_owner: dict = {}      # kp -> per-slot owning seq (or None)
         self._submit_seq = 0             # ownership tokens for ring slots
         self._warmed: set = set()        # (n_dims, kp) already compiled
+        self._tops: dict = {}            # n_dims -> ladder top, or None
         #: number of bucket-shape traces performed — a warmed backend must
         #: not grow this mid-run (the zero-compile probe in the tests)
         self.compile_count = 0
@@ -206,15 +260,62 @@ class EvalBackend:
         self._ring[kp] = (slot + 1) % STAGING_RING
         return ring[slot], slot
 
+    def _bucket_bytes(self, n_dims: int, kp: int) -> Optional[int]:
+        """Device bytes the compiled bucket of ``kp`` lanes asks for (its
+        temporaries, argument, output and code); None when the compiler
+        refuses it for want of memory."""
+        import jax
+
+        try:
+            compiled = self._eval.lower(jax.ShapeDtypeStruct(
+                (kp, n_dims + 1), np.float32)).compile()
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            return None
+        m = compiled.memory_analysis()
+        return (m.temp_size_in_bytes + m.argument_size_in_bytes
+                + m.output_size_in_bytes + m.generated_code_size_in_bytes)
+
+    def _derive_top(self, n_dims: int, want: int) -> Optional[int]:
+        """The widest power-of-two bucket that fits in the device's
+        headroom, estimated from the bytes a lane takes at the probe
+        bucket.  Where that estimate cuts a ladder wanted up to ``want``,
+        the top bucket itself is compiled and checked, halving until one
+        fits.  None where the device reports no memory limit."""
+        free = device_headroom()
+        if free is None:
+            return None
+        probe = bucket_size(PROBE_LANES, self.min_bucket)
+        need = self._bucket_bytes(n_dims, probe)
+        top = probe
+        if need is not None:
+            while need * (2 * top) // probe <= free:
+                top *= 2
+        if top >= want:
+            return top
+        while top > self.min_bucket:
+            need = self._bucket_bytes(n_dims, top)
+            if need is not None and need <= free:
+                break
+            top //= 2
+        return top
+
     def warm(self, n_dims: int, max_k: int) -> "EvalBackend":
-        """Compile AND execute the whole bucket ladder (min_bucket up to
-        ``bucket_size(max_k)``) so no compile ever lands mid-run, and
+        """Compile AND execute the bucket ladder (min_bucket up to
+        ``bucket_size(max_k)``, or to the ladder's top where the device's
+        memory cuts it lower) so no compile ever lands mid-run, and
         preallocate the persistent staging buffers.  Idempotent: already
         warmed (n_dims, bucket) cells are skipped, so re-warming at the
         start of every ``BatchedVolunteerGrid.run`` costs nothing."""
         handles = []
         kp = bucket_size(1, self.min_bucket)
         top = bucket_size(max_k, self.min_bucket)
+        if top > PROBE_LANES and n_dims not in self._tops:
+            self._tops[n_dims] = self._derive_top(n_dims, top)
+        bound = self._tops.get(n_dims)
+        if bound is not None:
+            top = min(top, bound)
         while True:
             if (n_dims, kp) not in self._warmed:
                 buf, _ = self._staging(kp, n_dims)
@@ -245,6 +346,9 @@ class EvalBackend:
         values."""
         k, n = pts.shape
         kp = bucket_size(k, self.min_bucket)
+        top = self._tops.get(n)
+        if top is not None and kp > top:
+            raise BucketTooWide(k, kp, top)
         buf, slot = self._staging(kp, n)
         self._submit_seq += 1
         self._slot_owner[kp][slot] = self._submit_seq

@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.core.fgdo import FgdoAnmServer, WorkUnit
 from repro.core.orchestrator.director import SearchSpec, dominated_cut
+from repro.obs.spans import spanned
 from repro.server import protocol
 from repro.server.registry import HostRegistry
 
@@ -346,19 +347,26 @@ class WorkServer:
             self.registry.sweep(self.now)
             self._last_sweep = self.now
         if self._next_deadline < self.now:
-            nxt = float("inf")
-            for k in list(self.leases):
-                l = self.leases[k]
-                if l.deadline < self.now:
-                    self.lapsed[k] = self.leases.pop(k)
-                    self._host_lease.pop(l.host_id, None)
-                    self._host_lapsed[l.host_id] = k
-                    self.counters.leases_lapsed += 1
-                    if self._tracer is not None:
-                        self._tracer.on_lapse(l.search_id, l.wu_id, self.now)
-                else:
-                    nxt = min(nxt, l.deadline)
-            self._next_deadline = nxt
+            self._lapse()
+
+    @spanned("intake.lapse")
+    def _lapse(self) -> None:
+        """Move every lease whose deadline has passed to the lapsed table,
+        and find the next deadline: one scan of the outstanding leases,
+        made each time the earliest deadline passes."""
+        nxt = float("inf")
+        for k in list(self.leases):
+            l = self.leases[k]
+            if l.deadline < self.now:
+                self.lapsed[k] = self.leases.pop(k)
+                self._host_lease.pop(l.host_id, None)
+                self._host_lapsed[l.host_id] = k
+                self.counters.leases_lapsed += 1
+                if self._tracer is not None:
+                    self._tracer.on_lapse(l.search_id, l.wu_id, self.now)
+            else:
+                nxt = min(nxt, l.deadline)
+        self._next_deadline = nxt
 
     def _drop_lapsed_for(self, host_id: int) -> None:
         """A host making contact supersedes its lapsed leases — they were

@@ -25,7 +25,10 @@ like the original.  Fitness evaluation is lazily batched: all in-flight
 points with unknown values go through ONE ``EvalBackend`` bucket (with
 on-device malicious-corruption lanes) the first time any of them is
 needed, which is what keeps the loopback server within striking distance
-of the direct batched grid in the benchmark overhead row.
+of the direct batched grid in the benchmark overhead row.  A batch stops
+at the backend's ladder top (the widest bucket the device's memory
+holds), so a fleet with more unknowns in flight pays more dispatches,
+never a compile.
 
 ``ConcurrentClientPool`` is the same fleet with the serialization removed
 (DESIGN.md §12): a coordinator thread owns the canonical virtual-time
@@ -50,6 +53,7 @@ subprocess, SIGKILLs it mid-search, and relaunches with ``--resume``.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import heapq
 import queue
@@ -61,7 +65,7 @@ import numpy as np
 
 from repro.core.grid import GridConfig, sample_hosts
 from repro.core.orchestrator.director import SearchSpec
-from repro.core.substrates.eval_backend import EvalBackend
+from repro.core.substrates.eval_backend import EvalBackend, ladder_top
 from repro.core.substrates.eval_cache import CachingSubmitter, EvalCache
 from repro.obs import spans
 from repro.server import protocol
@@ -105,6 +109,7 @@ class PoolStats:
     corrupted: int = 0                # malicious lanes evaluated
     eval_batches: int = 0
     evals: int = 0
+    cut_batches: int = 0              # lazy batches cut at the ladder top
     resumed_leases: int = 0           # in-flight work rebuilt after restore
     sim_time: float = 0.0
 
@@ -212,6 +217,14 @@ class SimClientPool:
         todo = sorted((inf.search, inf.wu, h)
                       for h, inf in self._inflight.items()
                       if (inf.search, inf.wu) not in self._ycache)
+        top = ladder_top(self.backend, len(self._inflight[todo[0][2]].point))
+        if top is not None and len(todo) > top:
+            # a fleet can hold more unknowns than the device's widest
+            # bucket: the first ``top`` in order, the asked key standing
+            # in for the last of them where it lies beyond
+            i = bisect.bisect_left(todo, key)
+            todo = todo[:top - 1] + [todo[max(i, top - 1)]]
+            self.stats.cut_batches += 1
         pts = np.stack([self._inflight[h].point for _, _, h in todo])
         mal_u = np.full(len(todo), np.nan)
         for i, (_, w, h) in enumerate(todo):
@@ -664,8 +677,9 @@ class ServerSubstrate:
         self.silence_frac = float(silence_frac)
         if warm:
             # in-flight unknowns are bounded by the fleet (≤ 1 lease per
-            # host), so warming the ladder to n_hosts guarantees zero
-            # compiles once the run starts
+            # host), so the ladder is warmed to n_hosts, or to its top
+            # where the device's memory cuts it lower: the pool's lazy
+            # batches stop at that top, so no compile lands mid-run
             self.backend.warm(len(np.asarray(self.specs[0].x0)),
                               fleet.n_hosts)
 

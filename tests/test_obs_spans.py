@@ -205,6 +205,52 @@ def test_served_loop_records_one_intake_span_a_message(on, monkeypatch):
     assert res.engines[0].best_fitness == res_off.engines[0].best_fitness
 
 
+def test_fleet_size_served_run_spans_its_lease_scans(on, monkeypatch):
+    """At 20,000 hosts the server's scans of the whole fleet are spanned:
+    ``intake.sweep`` over the registry, ``intake.lapse`` over the leases
+    each time the earliest deadline passes.  Cut after a fixed number of
+    messages, the traced run leaves the server's counters and the pool's
+    as the same run unwatched does."""
+    import dataclasses
+
+    from repro.core.substrates.eval_backend import InProcessEvalBackend
+    from repro.server.server import WorkServer
+    from repro.server.sim import (SimClientPool, SimulatedCrash,
+                                  smoke_problem)
+    from repro.server.transport import make_transport
+
+    spec, fleet, f_batch = smoke_problem(n_stars=64, n_hosts=20_000, m=24,
+                                         iterations=2)
+    backend = InProcessEvalBackend(f_batch, n_dims=8, max_bucket=64)
+
+    def cut_run():
+        # a lease of half a base evaluation time lapses on most hosts
+        server = WorkServer([spec], lease_timeout=0.5 * fleet.base_eval_time,
+                            idle_retry=fleet.idle_retry)
+        transport = make_transport("loopback")
+        transport.start(server.handle)
+        pool = SimClientPool(fleet, backend, max_messages=150_000)
+        with pytest.raises(SimulatedCrash):
+            pool.run(transport.connect())
+        transport.stop()
+        return (dataclasses.asdict(server.counters),
+                dataclasses.asdict(pool.stats))
+
+    counters, pool = cut_run()
+    tot = spans.totals()
+    assert counters["messages"] == tot["intake.transport"]["count"] == \
+        150_000
+    assert counters["leases_lapsed"] > 0 and counters["late_returns"] > 0
+    assert tot["intake.lapse"]["count"] > 0
+    assert tot["intake.sweep"]["count"] > 0
+    for name in ("intake.lapse", "intake.sweep"):
+        assert 0 < tot[name]["self_ns"] == tot[name]["total_ns"]
+    spans.reset()
+    monkeypatch.setattr(spans, "enabled", lambda: False)
+    assert cut_run() == (counters, pool)
+    assert spans.totals() == {}
+
+
 def test_core_layers_import_spans_without_the_operator_plane():
     code = ("import sys, repro.core.engine, "
             "repro.core.substrates.batched_grid; "
